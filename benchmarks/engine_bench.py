@@ -15,7 +15,7 @@ is scan/decode + shuffle materialization). Four comparisons:
                   interpreted op_hash_join + run_pipeline_ops +
                   radix_partition vs the compiled backend's fused
                   join->ops->partition tail (one traced call backed by the
-                  Pallas sorted-probe kernel).
+                  bucketed sorted probe).
 * dup_key_join  — the same fragment shape with DUPLICATE build keys
                   (skewed 1..4 multiplicity): interpreted expansion in
                   op_hash_join vs the compiled counts/prefix-sum range

@@ -15,6 +15,7 @@ import jax
 from repro.configs.registry import ARCHS
 from repro.core.storage_service import ObjectStore
 from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import Preempted, Trainer, TrainerConfig
 
 
@@ -30,7 +31,7 @@ def main() -> None:
             raise Preempted()
 
     print("phase 1: training on mesh (1,1), preempted at step 12")
-    t1 = Trainer(cfg, jax.make_mesh((1, 1), ("data", "model")), store, data,
+    t1 = Trainer(cfg, make_mesh(1, 1), store, data,
                  tcfg=TrainerConfig(total_steps=20, checkpoint_every=5,
                                     log_every=2),
                  preemption_hook=preempt_at_12)
@@ -39,7 +40,7 @@ def main() -> None:
           f"{out1['resumable_from']}")
 
     n_dev = jax.device_count()
-    mesh2 = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh2 = make_mesh(n_dev, 1)
     print(f"phase 2: elastic restart on mesh ({n_dev},1) "
           f"— state re-sharded from the object store")
     t2 = Trainer(cfg, mesh2, store, data,

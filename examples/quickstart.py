@@ -7,11 +7,10 @@ cost accounting.
 import argparse
 import dataclasses
 
-import jax
-
 from repro.configs.registry import ARCHS
 from repro.core.storage_service import ObjectStore
 from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -23,7 +22,7 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = dataclasses.replace(ARCHS[args.arch].reduced(), microbatches=2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh(1, 1)
     store = ObjectStore()
     trainer = Trainer(
         cfg, mesh, store,

@@ -6,16 +6,16 @@ break-even (the paper's Table-6 economics at serve time).
 """
 import time
 
-import jax
 import numpy as np
 
 from repro.configs.registry import ARCHS
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import Request, ServingEngine
 
 
 def main() -> None:
     cfg = ARCHS["internlm2-1.8b"].reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh(1, 1)
     engine = ServingEngine(cfg, mesh, batch_size=4, max_prompt=16,
                            max_len=32)
 

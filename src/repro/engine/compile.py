@@ -12,8 +12,8 @@ specs into fused kernels:
   evaluate in one fused computation over the already-compacted rows.
 * ``hash_join`` (plus every following ``filter``/``project``, plus the
   shuffle's radix partition assignment when the run reaches the fragment
-  output) lowers into ONE traced function (``_FusedTail``): the Pallas
-  sorted-probe kernel (``kernels.hash_join``) locates each probe key in
+  output) lowers into ONE traced function (``_FusedTail``): the bucketed
+  sorted probe (``kernels.hash_join``) locates each probe key in
   the argsorted build side, downstream predicates AND into the join's
   match mask with no intermediate materialization, derived projections
   evaluate over the probed columns, and the shuffle ``key % r`` partition
@@ -88,11 +88,8 @@ from repro.engine import operators
 from repro.engine import plans as engine_plans
 from repro.engine.columnar import ColumnBatch
 from repro.kernels import hash_join as hj_kernel
+from repro.kernels import tpu
 from repro.kernels.segment_reduce import segment_reduce
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -402,12 +399,15 @@ _INT32_MIN = np.iinfo(np.int32).min
 
 # The int32 fallback warning fires once per process: silent per-fragment
 # warnings would flood a query's log, silence would hide that a "jit"
-# query is quietly running its joins interpreted.
+# query is quietly running its joins interpreted. The counter counts every
+# such fallback (per fragment call), for callers that must see each one.
 _INT32_FALLBACK_WARNED = False
+FALLBACK_STATS = {"int32_join": 0}
 
 
 def _warn_int32_fallback(detail: str) -> None:
     global _INT32_FALLBACK_WARNED
+    FALLBACK_STATS["int32_join"] += 1
     if _INT32_FALLBACK_WARNED:
         return
     _INT32_FALLBACK_WARNED = True
@@ -455,7 +455,7 @@ def _run_fused(batch: ColumnBatch, segment: list[dict]) -> ColumnBatch:
 #
 # A tail is ``[hash_join?] + (filter|project)*`` optionally terminated by
 # the fragment's shuffle partition. One traced function computes the probe
-# (Pallas sorted-probe kernel over the argsorted build side), the fused
+# (bucketed sorted probe over the argsorted build side), the fused
 # predicate mask, every derived projection, and the radix partition
 # assignment (``r`` = dead-row sentinel for unmatched/filtered rows); the
 # host then derives the stable partition permutation with one radix
@@ -708,8 +708,7 @@ class _FusedTail:
             if join is not None:
                 pos, match = hj_kernel.sorted_probe(
                     bkeys, env[join["left_key"]].astype(jnp.int32),
-                    scalars=scalars, starts=starts, iters=iters,
-                    interpret=_interpret())
+                    scalars=scalars, starts=starts, iters=iters)
                 match = match & valid
                 for c in right_in:
                     env[c] = bpayload[c][pos]
@@ -730,7 +729,7 @@ class _FusedTail:
             valid = jnp.arange(n, dtype=jnp.int32) < n_valid
             lo, hi, match = hj_kernel.sorted_probe_range(
                 bkeys, lkeys.astype(jnp.int32), scalars=scalars,
-                starts=starts, iters=iters, interpret=_interpret())
+                starts=starts, iters=iters)
             return lo, jnp.where(match & valid, hi - lo, 0)
 
         return count_fn
@@ -1024,7 +1023,7 @@ def _run_hash_agg(batch: ColumnBatch, keys: list[str],
                 vals[row, :n] = v[order] if order is not None else v
             red = np.asarray(segment_reduce(vals, ids, num_segments=s_pad,
                                             mode=mode,
-                                            interpret=_interpret()))
+                                            interpret=tpu.interpret()))
             for row, (name, _) in enumerate(group):
                 out[name] = red[row, :n_groups].astype(np.float64)
     for name, fn, _ in aggs:

@@ -15,6 +15,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tpu
 
 NEG_INF = -2.3819763e38
 
@@ -101,24 +104,11 @@ def flash_attention_hmajor(q, k, v, *, causal: bool = True, window: int = 0,
                                lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
-            _vmem((block_q, 1), jnp.float32),   # m: running max
-            _vmem((block_q, 1), jnp.float32),   # l: running denom
-            _vmem((block_q, d), jnp.float32),   # acc: running numerator
+            pltpu.VMEM((block_q, 1), jnp.float32),   # m: running max
+            pltpu.VMEM((block_q, 1), jnp.float32),   # l: running denom
+            pltpu.VMEM((block_q, d), jnp.float32),   # acc: running numerator
         ],
-        compiler_params=_tpu_params(("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+        compiler_params=tpu.params(("parallel", "parallel", "parallel",
+                                    "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params(dimension_semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None

@@ -1,4 +1,4 @@
-"""Pallas sorted-probe kernel for the query engine's compiled equi-join.
+"""Sorted probe for the query engine's compiled equi-join.
 
 Backs ``hash_join`` in the jit backend: the build side arrives sorted by
 key (the compiler argsorts it host-side, as the interpreted join does) and
@@ -6,13 +6,11 @@ every probe key is located with a bucket-accelerated lower-bound search:
 
 * A radix bucket table over the high key bits (``prepare_buckets``, built
   host-side with one 64K-entry ``np.searchsorted``) narrows each probe to
-  a small slice of the build array, so the in-kernel binary search needs
-  only ``ceil(log2(max bucket population)) + 1`` steps — ~4 for uniform
-  TPC keys instead of ``log2(build rows)`` (~18 at SF laptop scale). That
-  makes the probe several times faster than an unbucketed search (or
-  ``np.searchsorted``): each step is one vector gather + compare over the
-  whole probe block.
-* The kernel emits, per probe row, the matching build position and a
+  a small slice of the build array, so the binary search needs only
+  ``ceil(log2(max bucket population)) + 1`` steps — ~4 for uniform TPC
+  keys instead of ``log2(build rows)`` (~21 at a TPC-H SF1 fragment).
+  Each step is one gather + compare over all probe keys.
+* The probe emits, per probe row, the matching build position and a
   match flag. The caller gathers payload columns with the positions —
   either inside the same ``jax.jit`` trace (derived columns) or in numpy
   (pass-through columns keep their original dtype).
@@ -22,12 +20,15 @@ every probe key is located with a bucket-accelerated lower-bound search:
   The compiled join's counts/prefix-sum expansion is built on it
   (``engine.compile._FusedTail``).
 
-Like the other kernels in this package, interpret mode gives bit-accurate
-execution on CPU; the body is plain vector compute plus gathers, which
-Mosaic lowers only partially today, so TPU deployments should keep
-``interpret`` until the gather path is tiled (ROADMAP note). Rows padded
-into the block are probed like any other row; callers mask them out with
-the returned positions' match flag plus their own validity mask.
+The search is plain ``jax.numpy``, traced into the caller's ``jax.jit``
+function, and is the same code on every platform. It is not a Pallas
+kernel: its steps are gathers at data-dependent positions anywhere in
+the build array, and Mosaic lowers only 2D gathers within a block
+(``NotImplementedError: Only 2D gather is supported`` for the 1D form),
+while a whole 1.5M-row build side does not fit the chip's scoped VMEM
+(``docs/BACKENDS.md``). XLA lowers these gathers on the TPU directly.
+Rows padded past the probe length are probed like any other row; callers
+mask them out with the match flag plus their own validity mask.
 """
 from __future__ import annotations
 
@@ -37,9 +38,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-
-from repro.kernels.segment_reduce import _tpu_params
 
 # Bucket table resolution: 2**16 buckets cover any int32 key span with a
 # <=256 KiB starts table (fits VMEM alongside the build block).
@@ -90,13 +88,10 @@ def prepare_buckets(build_sorted: np.ndarray) -> tuple[np.ndarray,
     return np.asarray([bias, shift], np.int32), starts, _round_depth(iters)
 
 
-def _probe_kernel(scal_ref, starts_ref, build_ref, keys_ref,
-                  pos_ref, match_ref, *, iters: int):
-    bias, shift = scal_ref[0, 0], scal_ref[0, 1]
-    starts = starts_ref[0]
-    build = build_ref[0]
-    keys = keys_ref[0]
-    s_pad = build.shape[0]
+def _bucket_bounds(scalars, starts, keys):
+    """Per probe key, the ``[lo, hi)`` slice of the sorted build side that
+    its bucket covers."""
+    bias, shift = scalars[0], scalars[1]
     # The build key span can exceed int31, so the int32 difference may
     # wrap; reinterpreting it as uint32 recovers the true offset for any
     # key >= bias (two's complement), and keys below bias wrap to huge
@@ -105,120 +100,44 @@ def _probe_kernel(scal_ref, starts_ref, build_ref, keys_ref,
     diff = (keys - bias).astype(jnp.uint32)
     bucket = jnp.minimum(diff >> shift.astype(jnp.uint32),
                          jnp.uint32(NB - 1)).astype(jnp.int32)
-    lo = starts[bucket]
-    hi = starts[bucket + 1]
-    for _ in range(iters):            # static depth: one gather+cmp per step
-        active = lo < hi
-        mid = (lo + hi) >> 1
-        go = (build[jnp.minimum(mid, s_pad - 1)] < keys) & active
-        lo = jnp.where(go, mid + 1, lo)
-        hi = jnp.where(active & ~go, mid, hi)
-    s = starts[NB]                    # true (unpadded) build length
-    pos = jnp.minimum(lo, s_pad - 1)
-    pos_ref[0] = pos
-    match_ref[0] = (build[pos] == keys) & (lo < s)
+    return starts[bucket], starts[bucket + 1]
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "interpret"))
-def _sorted_probe_call(scalars, starts, build_sorted, keys, *, iters: int,
-                       interpret: bool):
-    s_pad = build_sorted.shape[0]
-    n = keys.shape[0]
-    pos, match = pl.pallas_call(
-        functools.partial(_probe_kernel, iters=iters),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((1, NB + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, s_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, n), jnp.bool_),
-        ],
-        compiler_params=_tpu_params(("arbitrary",)),
-        interpret=interpret,
-    )(scalars[None, :], starts[None, :], build_sorted[None, :],
-      keys[None, :])
-    return pos[0], match[0]
-
-
-def _probe_range_kernel(scal_ref, starts_ref, build_ref, keys_ref,
-                        lo_ref, hi_ref, match_ref, *, iters: int):
-    """Like ``_probe_kernel`` but emits the full duplicate range: per
-    probe key, the lower bound ``lo`` and upper bound ``hi`` into the
-    sorted build side (``hi - lo`` = match multiplicity). Two static-depth
-    binary searches share the bucket narrowing; the upper bound uses a
-    ``<=`` comparator (first position strictly greater than the key)."""
-    bias, shift = scal_ref[0, 0], scal_ref[0, 1]
-    starts = starts_ref[0]
-    build = build_ref[0]
-    keys = keys_ref[0]
+def _bound(build, keys, lo, hi, iters: int, upper: bool):
+    """Static-depth binary search inside ``[lo, hi)``: the first position
+    whose key is not below (``upper``: not at or below) the probe key."""
     s_pad = build.shape[0]
-    diff = (keys - bias).astype(jnp.uint32)   # see _probe_kernel: wrap-safe
-    bucket = jnp.minimum(diff >> shift.astype(jnp.uint32),
-                         jnp.uint32(NB - 1)).astype(jnp.int32)
-    b_lo = starts[bucket]
-    b_hi = starts[bucket + 1]
-    lo, hi = b_lo, b_hi
-    for _ in range(iters):            # lower bound: first pos not < key
+    for _ in range(iters):            # one gather + compare per step
         active = lo < hi
         mid = (lo + hi) >> 1
-        go = (build[jnp.minimum(mid, s_pad - 1)] < keys) & active
+        b = build[jnp.minimum(mid, s_pad - 1)]
+        go = ((b <= keys) if upper else (b < keys)) & active
         lo = jnp.where(go, mid + 1, lo)
         hi = jnp.where(active & ~go, mid, hi)
-    ulo, uhi = b_lo, b_hi
-    for _ in range(iters):            # upper bound: first pos > key
-        active = ulo < uhi
-        mid = (ulo + uhi) >> 1
-        go = (build[jnp.minimum(mid, s_pad - 1)] <= keys) & active
-        ulo = jnp.where(go, mid + 1, ulo)
-        uhi = jnp.where(active & ~go, mid, uhi)
-    s = starts[NB]                    # true (unpadded) build length
-    pos = jnp.minimum(lo, s_pad - 1)
-    lo_ref[0] = lo
-    hi_ref[0] = ulo
-    match_ref[0] = (build[pos] == keys) & (lo < s)
+    return lo
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iters",))
+def _sorted_probe_call(scalars, starts, build_sorted, keys, *, iters: int):
+    b_lo, b_hi = _bucket_bounds(scalars, starts, keys)
+    lo = _bound(build_sorted, keys, b_lo, b_hi, iters, upper=False)
+    pos = jnp.minimum(lo, build_sorted.shape[0] - 1)
+    # starts[NB] is the true (unpadded) build length.
+    return pos, (build_sorted[pos] == keys) & (lo < starts[NB])
+
+
+@functools.partial(jax.jit, static_argnames=("iters",))
 def _sorted_probe_range_call(scalars, starts, build_sorted, keys, *,
-                             iters: int, interpret: bool):
-    s_pad = build_sorted.shape[0]
-    n = keys.shape[0]
-    lo, hi, match = pl.pallas_call(
-        functools.partial(_probe_range_kernel, iters=iters),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((1, NB + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, s_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, n), jnp.bool_),
-        ],
-        compiler_params=_tpu_params(("arbitrary",)),
-        interpret=interpret,
-    )(scalars[None, :], starts[None, :], build_sorted[None, :],
-      keys[None, :])
-    return lo[0], hi[0], match[0]
+                             iters: int):
+    b_lo, b_hi = _bucket_bounds(scalars, starts, keys)
+    lo = _bound(build_sorted, keys, b_lo, b_hi, iters, upper=False)
+    hi = _bound(build_sorted, keys, b_lo, b_hi, iters, upper=True)
+    pos = jnp.minimum(lo, build_sorted.shape[0] - 1)
+    return lo, hi, (build_sorted[pos] == keys) & (lo < starts[NB])
 
 
 def sorted_probe_range(build_sorted, keys, *, scalars=None, starts=None,
-                       iters: int | None = None, interpret: bool = False):
+                       iters: int | None = None):
     """Range probe of ``keys`` (n,) into sorted ``build_sorted`` (s,).
 
     Returns ``(lo, hi, match)``: ``[lo[i], hi[i])`` is the contiguous run
@@ -235,7 +154,7 @@ def sorted_probe_range(build_sorted, keys, *, scalars=None, starts=None,
                                     jnp.asarray(starts, jnp.int32),
                                     jnp.asarray(build_sorted, jnp.int32),
                                     jnp.asarray(keys, jnp.int32),
-                                    iters=iters, interpret=interpret)
+                                    iters=iters)
 
 
 def sorted_probe_range_np(build_sorted: np.ndarray, keys: np.ndarray
@@ -247,7 +166,7 @@ def sorted_probe_range_np(build_sorted: np.ndarray, keys: np.ndarray
 
 
 def sorted_probe(build_sorted, keys, *, scalars=None, starts=None,
-                 iters: int | None = None, interpret: bool = False):
+                 iters: int | None = None):
     """Lower-bound probe of ``keys`` (n,) into sorted ``build_sorted`` (s,).
 
     Returns ``(pos, match)``: ``pos[i]`` is the first build position whose
@@ -265,7 +184,7 @@ def sorted_probe(build_sorted, keys, *, scalars=None, starts=None,
                               jnp.asarray(starts, jnp.int32),
                               jnp.asarray(build_sorted, jnp.int32),
                               jnp.asarray(keys, jnp.int32),
-                              iters=iters, interpret=interpret)
+                              iters=iters)
 
 
 def sorted_probe_np(build_sorted: np.ndarray, keys: np.ndarray

@@ -12,6 +12,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tpu
 
 
 def _gmm_kernel(x_ref, w_ref, y_ref, acc_scr, *, d_steps: int):
@@ -58,21 +61,8 @@ def gmm(x, w, *, block_c: int = 128, block_f: int = 128, block_d: int = 512,
         out_specs=pl.BlockSpec((1, block_c, block_f),
                                lambda e_, ic, jf, kd: (e_, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
-        scratch_shapes=[_vmem((block_c, block_f), jnp.float32)],
-        compiler_params=_tpu_params(("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
+        compiler_params=tpu.params(("parallel", "parallel", "parallel",
+                                    "arbitrary")),
         interpret=interpret,
     )(x, w)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params(dimension_semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None

@@ -16,6 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tpu
 
 
 def _rglru_kernel(la_ref, b_ref, h0_ref, o_ref, hlast_ref, h_scr, *,
@@ -71,21 +74,8 @@ def rglru_scan_blocked(log_a, b_in, h0, *, chunk: int = 256,
             jax.ShapeDtypeStruct((b, s, w), jnp.float32),
             jax.ShapeDtypeStruct((b, w), jnp.float32),
         ],
-        scratch_shapes=[_vmem((1, block_w), jnp.float32)],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        compiler_params=tpu.params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b_in, h0)
     return h_all, h_last
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params(dimension_semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None

@@ -22,6 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tpu
 
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref,
@@ -98,21 +101,8 @@ def rwkv6_scan_hmajor(r, k, v, log_w, u, s0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((b, h, s, vd), r.dtype),
             jax.ShapeDtypeStruct((b, h, kd, vd), jnp.float32),
         ],
-        scratch_shapes=[_vmem((kd, vd), jnp.float32)],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((kd, vd), jnp.float32)],
+        compiler_params=tpu.params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, log_w, u, s0)
     return o, s_out
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params(dimension_semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None

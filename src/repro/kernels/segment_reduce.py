@@ -4,25 +4,29 @@ Backs the query engine's ``hash_agg`` in the jit backend: values arrive
 sorted by group (the compiler lexsorts the keys), each row carrying its
 group id, and the kernel reduces every segment to one output slot.
 
-Per grid step a ``(C, block_n)`` slab of value columns is expanded against
-a ``(block_n, num_segments)`` one-hot membership matrix; ``sum``/``count``
-reduce each column with a PAIRWISE binary tree over the masked
-``(bn, S)`` contributions (block sizes are powers of two, so the tree
-halves cleanly), and ``min``/``max`` use masked VPU reductions — both
-accumulated into a persistent output block across grid steps (sequential
-minor-most grid dimension, as in ``moe_gmm``). Rows padded to the block
-size carry segment id ``-1`` and match no column.
+The grid is ``(segment blocks, row blocks)``. Per step a ``(C, block_n)``
+slab of value columns is expanded against a ``(block_n, seg_block)``
+one-hot membership matrix; ``sum``/``count`` reduce each column with a
+PAIRWISE binary tree over the masked ``(bn, S)`` contributions (block
+sizes are powers of two, so the tree halves cleanly), and ``min``/``max``
+use masked VPU reductions — both accumulated into the segment block's
+output across the row steps (sequential minor-most grid dimension, as in
+``moe_gmm``). Rows padded to the block size carry segment id ``-1`` and
+match no column.
 
 The pairwise tree is what closes the float-parity gap with the float64
 numpy backend: a sequential (or matmul-K-loop) float32 accumulation over
 ``k`` same-sign values loses ``O(k * eps)`` relative precision — ~1e-4 at
 TPC fragment sizes — while the tree's error is ``O(log2(k) * eps)``,
-~7e-7 even at million-row blocks. That is what lets the engine promise
-aggregate parity at rtol=1e-6 (see ``docs/BACKENDS.md``) and run the jit
-backend as the default. Like the other kernels in this package, interpret
-mode gives bit-accurate execution on CPU; on TPU the same body compiles
-to Mosaic. Interpret mode executes one eager dispatch per grid step, so
-on CPU the default block covers the whole array in one step.
+~7e-7 even at million-row blocks. Block sums then accumulate across row
+blocks with Kahan compensation, which matters on the TPU, where VMEM
+bounds a block to a few thousand rows and a 1.5M-row fragment takes
+hundreds of them. That is what lets the engine promise aggregate parity
+at rtol=1e-6 (see ``docs/BACKENDS.md``) and run the jit backend as the
+default. Interpret mode gives bit-accurate execution on CPU; on TPU the
+same body compiles to Mosaic. Interpret mode executes one eager dispatch
+per grid step, so on CPU the block covers the whole array in one step
+up to an element budget.
 """
 from __future__ import annotations
 
@@ -32,25 +36,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tpu
 
 _INIT = {"sum": 0.0, "count": 0.0, "min": jnp.inf, "max": -jnp.inf}
 
+# Interpret mode: one eager dispatch per grid step, so blocks are as large
+# as an element budget allows (block_n x s_pad <= ~64 MiB float32).
 _INTERPRET_MAX_BLOCK = 1 << 20
-# Cap block_n x s_pad in interpret mode (~64 MiB float32 per step).
 _ONEHOT_ELEM_BUDGET = 1 << 24
+# Mosaic: the (block_n, seg_block) one-hot and its pairwise tree live in
+# VMEM. 2 MiB of float32 per block keeps the kernel well inside the
+# chip's scoped VMEM (4096 x 128 at <= 128 groups, 512 x 1024 above).
+_TPU_ONEHOT_ELEMS = 1 << 19
+_TPU_SEG_BLOCK = 1024
 
 
-def _segment_reduce_kernel(vals_ref, ids_ref, out_ref, *, mode: str):
-    step = pl.program_id(0)
+def _segment_reduce_kernel(vals_ref, ids_ref, out_ref, *comp_ref, mode: str):
+    row_step = pl.program_id(1)
+    s_blk = out_ref.shape[-1]
 
-    @pl.when(step == 0)
+    @pl.when(row_step == 0)
     def _init():
         out_ref[...] = jnp.full_like(out_ref, _INIT[mode])
+        for ref in comp_ref:
+            ref[...] = jnp.zeros_like(ref)
 
     vals = vals_ref[...].astype(jnp.float32)           # (C, bn)
-    ids = ids_ref[0]                                   # (bn,) int32
-    n_seg = out_ref.shape[-1]
-    seg = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], n_seg), 1)
+    # Segment ids relative to this grid step's segment block; ids of other
+    # blocks (and the -1 padding) fall outside [0, s_blk) and match nothing.
+    ids = ids_ref[0] - pl.program_id(0) * s_blk        # (bn,) int32
+    seg = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], s_blk), 1)
     onehot = ids[:, None] == seg                       # (bn, S)
     if mode in ("sum", "count"):
         # Pairwise binary tree over the masked contributions instead of a
@@ -61,13 +78,20 @@ def _segment_reduce_kernel(vals_ref, ids_ref, out_ref, *, mode: str):
         # halves cleanly; C is static and small.
         if mode == "count":
             vals = jnp.ones_like(vals)
+        comp = comp_ref[0]
         for c in range(vals.shape[0]):
             t = jnp.where(onehot, vals[c][:, None], 0.0)   # (bn, S)
             m = t.shape[0]
             while m > 1:
                 m //= 2
                 t = t[:m] + t[m:2 * m]
-            out_ref[c] += t[0]
+            # Block sums accumulate across row blocks with Kahan
+            # compensation, so hundreds of blocks add O(eps), not
+            # O(blocks x eps), to the tree's error.
+            y = t[0] - comp[c]
+            acc = out_ref[c] + y
+            comp[c] = (acc - out_ref[c]) - y
+            out_ref[c] = acc
     elif mode in ("min", "max"):
         combine = jnp.minimum if mode == "min" else jnp.maximum
         sentinel = _INIT[mode]
@@ -79,46 +103,54 @@ def _segment_reduce_kernel(vals_ref, ids_ref, out_ref, *, mode: str):
         raise ValueError(f"unknown reduction mode {mode!r}")
 
 
+def _tiling(n: int, num_segments: int, interpret: bool) -> tuple[int, int]:
+    """``(block_n, seg_block)`` for ``n`` rows and ``num_segments`` slots.
+
+    Interpret mode takes every segment in one block and as many rows as
+    the element budget allows (fewer eager interpreter steps). Mosaic
+    takes 128-lane segment blocks of at most ``_TPU_SEG_BLOCK`` and rows up
+    to the VMEM budget. ``block_n`` is a power of two, so the in-kernel
+    pairwise tree halves cleanly (rounded down: rounding up could double
+    the one-hot memory)."""
+    if interpret:
+        seg_block = max(8, num_segments)
+        block_n = min(_INTERPRET_MAX_BLOCK, _ONEHOT_ELEM_BUDGET // seg_block)
+    else:
+        seg_block = min(_TPU_SEG_BLOCK, -(-num_segments // 128) * 128)
+        block_n = _TPU_ONEHOT_ELEMS // seg_block
+    bn = max(128, min(block_n, -(-n // 128) * 128))
+    return 1 << (bn.bit_length() - 1), seg_block
+
+
 @functools.partial(jax.jit, static_argnames=("num_segments", "mode",
-                                             "block_n", "interpret"))
+                                             "block_n", "seg_block",
+                                             "interpret"))
 def _segment_reduce_2d(vals, seg_ids, *, num_segments: int, mode: str,
-                       block_n: int | None, interpret: bool):
+                       block_n: int, seg_block: int, interpret: bool):
     c, n = vals.shape
-    # TPU tiling wants 128-lane alignment; the interpreter does not, and
-    # the one-hot expansion is O(block_n x s_pad) memory per step, so on
-    # CPU the block is as large as an element budget allows (fewer eager
-    # interpreter steps) but never unbounded in both dimensions at once.
-    s_pad = max(8, num_segments) if interpret \
-        else max(128, -(-num_segments // 128) * 128)
-    if block_n is None:
-        block_n = max(128, min(n, _INTERPRET_MAX_BLOCK,
-                               _ONEHOT_ELEM_BUDGET // s_pad)) \
-            if interpret else 4096
-    bn = min(block_n, max(128, -(-n // 128) * 128))
-    # Power-of-two block so the in-kernel pairwise sum tree halves
-    # cleanly (round down: rounding up could double the one-hot memory).
-    bn = max(128, 1 << (bn.bit_length() - 1)) if bn & (bn - 1) else bn
-    n_pad = -(-max(n, 1) // bn) * bn
+    s_pad = -(-num_segments // seg_block) * seg_block
+    n_pad = -(-max(n, 1) // block_n) * block_n
     vals = jnp.pad(vals, ((0, 0), (0, n_pad - n)))
     seg_ids = jnp.pad(seg_ids, (0, n_pad - n), constant_values=-1)
-
     out = pl.pallas_call(
         functools.partial(_segment_reduce_kernel, mode=mode),
-        grid=(n_pad // bn,),
+        grid=(s_pad // seg_block, n_pad // block_n),
         in_specs=[
-            pl.BlockSpec((c, bn), lambda i: (0, i)),
-            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((c, block_n), lambda j, i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda j, i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((c, s_pad), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((c, seg_block), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((c, s_pad), jnp.float32),
-        compiler_params=_tpu_params(("arbitrary",)),
+        scratch_shapes=[pltpu.VMEM((c, seg_block), jnp.float32)]
+        if mode in ("sum", "count") else [],
+        compiler_params=tpu.params(("parallel", "arbitrary")),
         interpret=interpret,
     )(vals, seg_ids[None, :])
     return out[:, :num_segments]
 
 
 def segment_reduce(vals, seg_ids, *, num_segments: int, mode: str = "sum",
-                   block_n: int | None = None, interpret: bool = False):
+                   interpret: bool = False):
     """Reduce ``vals`` into ``num_segments`` slots by ``seg_ids`` (n,).
 
     ``vals`` is ``(n,)`` for one column or ``(C, n)`` for a stack of
@@ -132,9 +164,10 @@ def segment_reduce(vals, seg_ids, *, num_segments: int, mode: str = "sum",
     squeeze = vals.ndim == 1
     if squeeze:
         vals = vals[None, :]
+    block_n, seg_block = _tiling(vals.shape[1], num_segments, interpret)
     out = _segment_reduce_2d(vals, seg_ids, num_segments=num_segments,
                              mode=mode, block_n=block_n,
-                             interpret=interpret)
+                             seg_block=seg_block, interpret=interpret)
     return out[0] if squeeze else out
 
 
@@ -151,10 +184,3 @@ def segment_reduce_np(vals: np.ndarray, seg_ids: np.ndarray,
         np.maximum.at(out, seg_ids, vals)
     return out
 
-
-def _tpu_params(dimension_semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None

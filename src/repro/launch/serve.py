@@ -4,10 +4,11 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro.configs.registry import ARCHS
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import Request, ServingEngine
 
 
@@ -17,10 +18,14 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-new-tokens", type=int, default=8)
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the published config (default is the "
+                         "reduced smoke config)")
     args = ap.parse_args()
 
-    cfg = ARCHS[args.arch].reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    compile_cache.enable()
+    cfg = ARCHS[args.arch] if args.full_config else ARCHS[args.arch].reduced()
+    mesh = make_mesh(1, 1)
     engine = ServingEngine(cfg, mesh, batch_size=args.batch_size,
                            max_prompt=16, max_len=32)
     rng = np.random.default_rng(0)

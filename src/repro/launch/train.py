@@ -9,11 +9,11 @@ from __future__ import annotations
 import argparse
 import dataclasses
 
-import jax
-
 from repro.configs.registry import ARCHS
 from repro.core.storage_service import ObjectStore
 from repro.data.pipeline import DataConfig
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -33,10 +33,11 @@ def main() -> None:
     ap.add_argument("--model", type=int, default=1)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = ARCHS[args.arch] if args.full_config else ARCHS[args.arch].reduced()
     cfg = dataclasses.replace(cfg, microbatches=min(cfg.microbatches,
                                                     args.global_batch))
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_mesh(args.data, args.model)
     trainer = Trainer(
         cfg, mesh, ObjectStore(),
         DataConfig(seq_len=args.seq_len, global_batch=args.global_batch),

@@ -3,8 +3,7 @@
 Two execution paths with identical math:
   * single-device (smoke tests, kernels oracle): dispatch/compute/combine
     on the local token set;
-  * expert-parallel (production): ``shard_map`` (via ``core.jax_compat``,
-    wherever the pinned jax puts it) over the (data, model)
+  * expert-parallel (production): ``jax.shard_map`` over the (data, model)
     mesh — tokens sharded batch x sequence, experts sharded over 'model',
     explicit ``all_to_all`` exchanges (GShard-style EP). The collective
     schedule is therefore visible to the roofline analysis.
@@ -23,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig
-from repro.core.jax_compat import shard_map
 from repro.models.common import Param, dense_init, shard, silu
 
 
@@ -225,8 +223,8 @@ def _moe_ep(params, x, cfg: ArchConfig, mesh, norm_topk: bool,
     # 'data' would reduce across *different token shards* (batch is
     # data-sharded). Expert-TP needs a mesh axis on which tokens are
     # replicated; on this 2D mesh there is none.
-    fn = shard_map(
-        local, mesh,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(in_x,
                   P(None, None),                       # router replicated
                   P("model", None, None),              # experts sharded,

@@ -11,15 +11,12 @@ import dataclasses
 import time
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import pricing
 from repro.launch import steps as step_factory
-from repro.models import transformer as tfm
-from repro.models.common import split_tree
 
 
 @dataclasses.dataclass
@@ -43,10 +40,7 @@ class ServingEngine:
         self.batch_size = batch_size
         self.max_prompt = max_prompt
         self.max_len = max_len
-        params, _ = split_tree(tfm.init_model(jax.random.PRNGKey(seed), cfg))
-        self.params = jax.tree.map(
-            lambda p: p.astype(cfg.activation_dtype)
-            if p.dtype == jnp.float32 else p, params)
+        self.params = step_factory.init_params(cfg, mesh, seed)
         self.prefill, _ = step_factory.make_prefill_step(cfg, mesh,
                                                          cache_len=max_len)
         self.decode, _ = step_factory.make_decode_step(cfg, mesh, batch_size,

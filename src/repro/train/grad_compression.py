@@ -7,9 +7,9 @@ bytes 4x vs fp32 / 2x vs bf16, with the quantization error fed back into
 the next step so convergence is preserved.
 
 ``compressed_psum`` runs the quantize -> psum -> dequantize pipeline inside
-``shard_map`` (via ``core.jax_compat`` — manual over the reduction axis
-only), so the collective payload really is int8 on the wire, visible in
-the dry-run HLO.
+``jax.shard_map`` (manual over the reduction axis only), so the
+collective payload really is int8 on the wire, visible in the dry-run
+HLO.
 """
 from __future__ import annotations
 
@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.jax_compat import shard_map
 
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -86,10 +85,10 @@ def compressed_psum(partials, error_state, mesh, axis: str = "pod"):
 
         in_spec = P(axis, *([None] * (g.ndim - 1)))
         out_spec = P(*([None] * (g.ndim - 1)))
-        return shard_map(local, mesh,
-                         in_specs=(in_spec, in_spec),
-                         out_specs=(out_spec, in_spec),
-                         check_replication=False)(g, e)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(in_spec, in_spec),
+                             out_specs=(out_spec, in_spec),
+                             check_vma=False)(g, e)
 
     flat_g, treedef = jax.tree.flatten(partials)
     flat_e = treedef.flatten_up_to(error_state)

@@ -19,7 +19,6 @@ import dataclasses
 import time
 from typing import Callable, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -29,8 +28,6 @@ from repro.core import pricing
 from repro.core.storage_service import ObjectStore
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.launch import steps as step_factory
-from repro.models import transformer as tfm
-from repro.models.common import split_tree
 from repro.train import optimizer as opt_mod
 
 
@@ -68,13 +65,7 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init_state(self):
-        params, _ = split_tree(
-            tfm.init_model(jax.random.PRNGKey(self.tcfg.seed), self.cfg))
-        params = jax.tree.map(
-            lambda p: p.astype(self.cfg.activation_dtype)
-            if p.dtype == jnp.float32 else p, params)
-        p_shard, o_shard, _ = self._shardings
-        params = jax.tree.map(jax.device_put, params, p_shard)
+        params = step_factory.init_params(self.cfg, self.mesh, self.tcfg.seed)
         opt_state = opt_mod.init_opt_state(params, self.opt_cfg)
         return params, opt_state
 

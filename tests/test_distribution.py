@@ -1,6 +1,8 @@
 """Distribution tests that need multiple devices run in subprocesses so the
 XLA host-device-count flag never leaks into the main test process (the
-dry-run brief requires smoke tests to see 1 device)."""
+dry-run brief requires smoke tests to see 1 device). The children run on
+the CPU's virtual devices: a chip the parent process holds is not theirs
+to ask for."""
 import json
 import os
 import subprocess
@@ -12,13 +14,14 @@ import jax
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import rules as shrules
 
 REPO = Path(__file__).resolve().parents[1]
 
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -38,7 +41,7 @@ class _FakeMesh:
 
 
 def test_pspec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh(1, 1)
     # heads=10 not divisible by model=1? size-1 axes never shard
     spec = shrules.pspec_for((512, 10, 64), ("embed", "heads", "head_dim"),
                              mesh)
@@ -46,7 +49,7 @@ def test_pspec_divisibility_fallback():
 
 
 def test_pspec_no_duplicate_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh(1, 1)
     spec = shrules.pspec_for((64, 64), ("ff", "ff"), mesh)
     flat = [s for s in spec if s is not None]
     assert len(flat) == len(set(flat))
@@ -57,6 +60,7 @@ def test_train_and_serve_sharded_execution():
     train step runs, loss finite; MoE EP path (shard_map all_to_all) used."""
     out = _run_subprocess("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import ARCHS
         from repro.launch.steps import make_train_step
         from repro.launch import inputs
@@ -65,7 +69,7 @@ def test_train_and_serve_sharded_execution():
         from repro.models import transformer as tfm
         from repro.models.common import split_tree
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(2, 4)
         for name in ("deepseek-7b", "qwen3-moe-235b-a22b"):
             cfg = dataclasses.replace(
                 ARCHS[name].reduced(), num_heads=8, num_kv_heads=4,
@@ -91,6 +95,7 @@ def test_moe_ep_equals_single_device():
     """EP (shard_map + all_to_all) must equal the single-device MoE math."""
     out = _run_subprocess("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import ARCHS
         from repro.models import moe as moe_mod
         from repro.models.common import split_tree
@@ -108,7 +113,7 @@ def test_moe_ep_equals_single_device():
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((2, 8, cfg.d_model)), jnp.float32)
         y1, a1 = moe_mod.moe_layer(params, x, cfg, mesh=None)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(2, 4)
         y2, a2 = jax.jit(lambda p_, x_: moe_mod.moe_layer(
             p_, x_, cfg, mesh=mesh))(params, x)
         err = float(jnp.max(jnp.abs(y1 - y2)))
@@ -122,6 +127,7 @@ def test_decode_ep_psum_path():
     """Decode (S=1) uses the psum EP path; equals single-device."""
     out = _run_subprocess("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import ARCHS
         from repro.models import moe as moe_mod
 
@@ -135,7 +141,7 @@ def test_decode_ep_psum_path():
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((4, 1, cfg.d_model)), jnp.float32)
         y1, _ = moe_mod.moe_layer(params, x, cfg, mesh=None)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh(2, 4)
         y2, _ = jax.jit(lambda p_, x_: moe_mod.moe_layer(
             p_, x_, cfg, mesh=mesh))(params, x)
         err = float(jnp.max(jnp.abs(y1 - y2)))
@@ -150,9 +156,10 @@ def test_grad_compression_ef_int8():
     feedback keeps the running mean unbiased."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.train import grad_compression as gc
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh(2, 2, pod=2)
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.standard_normal((2, 16, 16)), jnp.float32)}
         e = {"w": jnp.zeros((2, 16, 16), jnp.float32)}
@@ -184,13 +191,14 @@ def test_small_dryrun_multipod_cell():
     lowers+compiles with the pod axis and the roofline extraction works."""
     out = _run_subprocess("""
         import dataclasses, json, jax
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import ARCHS
         from repro.configs.base import ShapeConfig
         from repro.launch.steps import make_train_step
         from repro.launch import inputs, hlo_analysis
         from repro.train.optimizer import AdamWConfig
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh(2, 2, pod=2)
         cfg = dataclasses.replace(ARCHS["internlm2-1.8b"].reduced(),
                                   microbatches=2)
         shape = ShapeConfig("t", 32, 8, "train")

@@ -11,6 +11,7 @@ from repro.checkpoint import object_store_ckpt as ckpt
 from repro.configs.registry import ARCHS
 from repro.core.storage_service import ObjectStore
 from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import Preempted, Trainer, TrainerConfig
 
 
@@ -21,7 +22,7 @@ def small_cfg():
 
 
 def _mesh(data=1, model=1):
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(data, model)
 
 
 def test_checkpoint_roundtrip():

@@ -232,7 +232,7 @@ def test_sorted_probe_matches_oracle(rng, n, s, lo, hi):
     build = np.sort(rng.choice(np.arange(lo, hi), size=s, replace=False)
                     ).astype(np.int32)
     keys = rng.integers(lo - 10, hi + 10, n).astype(np.int32)
-    pos, match = sorted_probe(build, keys, interpret=True)
+    pos, match = sorted_probe(build, keys)
     ref_pos, ref_match = sorted_probe_np(build, keys)
     np.testing.assert_array_equal(np.asarray(match), ref_match)
     np.testing.assert_array_equal(np.asarray(pos)[ref_match],
@@ -244,7 +244,7 @@ def test_sorted_probe_duplicate_build_keys_lower_bound(rng):
     (lower bound), which the engine relies on to detect duplicates."""
     build = np.sort(rng.integers(0, 50, 300)).astype(np.int32)
     keys = np.arange(-5, 60, dtype=np.int32)
-    pos, match = sorted_probe(build, keys, interpret=True)
+    pos, match = sorted_probe(build, keys)
     ref_pos, ref_match = sorted_probe_np(build, keys)
     np.testing.assert_array_equal(np.asarray(match), ref_match)
     np.testing.assert_array_equal(np.asarray(pos)[ref_match],
@@ -264,7 +264,7 @@ def test_sorted_probe_range_matches_oracle(rng, n, s, kmax, dup_frac):
     dups = rng.choice(base, int(s * dup_frac))
     build = np.sort(np.concatenate([base, dups])).astype(np.int32)
     keys = rng.integers(-10, kmax + 10, n).astype(np.int32)
-    lo, hi, match = sorted_probe_range(build, keys, interpret=True)
+    lo, hi, match = sorted_probe_range(build, keys)
     lo, hi, match = np.asarray(lo), np.asarray(hi), np.asarray(match)
     ref_lo, ref_hi, ref_match = sorted_probe_range_np(build, keys)
     np.testing.assert_array_equal(match, ref_match)
@@ -283,7 +283,7 @@ def test_prepare_buckets_depth_covers_skew(rng):
     keys = np.concatenate([np.zeros(10, np.int32),
                            rng.integers(0, 2**30, 100).astype(np.int32)])
     pos, match = sorted_probe(build, keys, scalars=scal, starts=starts,
-                              iters=iters, interpret=True)
+                              iters=iters)
     ref_pos, ref_match = sorted_probe_np(build, keys)
     np.testing.assert_array_equal(np.asarray(match), ref_match)
     np.testing.assert_array_equal(np.asarray(pos)[ref_match],

@@ -62,7 +62,7 @@ def test_plan_shape_hash_stable_across_processes():
             "print(plans.plan_shape_hash(queries.q12_plan()))\n")
     seen = set()
     for seed in ("0", "1", "1234"):
-        env = dict(os.environ, PYTHONHASHSEED=seed,
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
                    PYTHONPATH="src" + os.pathsep
                    + os.environ.get("PYTHONPATH", ""))
         out = subprocess.run([sys.executable, "-c", code], env=env,

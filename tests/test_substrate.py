@@ -10,6 +10,7 @@ import pytest
 from repro.configs.registry import ARCHS
 from repro.data.pipeline import DataConfig, TokenPipeline, pack_sequences
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tfm
 from repro.models.common import split_tree
 from repro.train import optimizer as opt
@@ -108,7 +109,7 @@ def test_chunked_block_scan_matches(rng):
 def test_serving_engine_completes_requests():
     from repro.serve.engine import Request, ServingEngine
     cfg = ARCHS["musicgen-medium"].reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh(1, 1)
     eng = ServingEngine(cfg, mesh, batch_size=2, max_prompt=8, max_len=16)
     rng = np.random.default_rng(0)
     reqs = [Request(i, rng.integers(0, cfg.vocab_size, 6), max_new_tokens=4)

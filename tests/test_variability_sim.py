@@ -70,7 +70,7 @@ def test_sampling_is_hash_salt_independent():
             "print(json.dumps(t, sort_keys=True, default=float))\n")
     seen = set()
     for seed in ("0", "1", "1234"):
-        env = dict(os.environ, PYTHONHASHSEED=seed,
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
                    PYTHONPATH="src" + os.pathsep
                    + os.environ.get("PYTHONPATH", ""))
         out = subprocess.run([sys.executable, "-c", code], env=env,
