@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core import pricing
 from repro.core.partition_scaling import PartitionModel
+from repro.core.spans import spanned
 
 MIB = 1024.0 ** 2
 GIB = 1024.0 ** 3
@@ -380,6 +381,8 @@ class ObjectStore:
             self.breaker.record_success()
 
     # -- S3-shaped API ------------------------------------------------------
+    @spanned("engine.store.put", lambda self, key, data: {
+        "bytes": len(data), "tier": self.tier})
     def put(self, key: str, data: bytes) -> None:
         self._guard(key)
         self._admit(key, write=True, nbytes=len(data))
@@ -408,6 +411,7 @@ class ObjectStore:
                 raise KeyError(key)
             return self._etags[key]
 
+    @spanned("engine.store.get", lambda self, *_, **__: {"tier": self.tier})
     def get(self, key: str, byte_range: Optional[tuple[int, int]] = None) -> bytes:
         self._guard(key)
         if self.chaos is not None and self.chaos.throttle(key, self._clock()):

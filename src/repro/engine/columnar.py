@@ -30,6 +30,8 @@ from typing import Iterable, Optional
 
 import numpy as np
 
+from repro.core.spans import span, spanned
+
 
 class ColumnBatch(dict):
     """dict[str, np.ndarray] with row-count invariants and helpers."""
@@ -72,6 +74,15 @@ class ColumnBatch(dict):
             {k: np.concatenate([b[k] for b in batches]) for k in keys})
 
 
+def _rows(batch, *_, **__) -> dict:
+    return {"rows": batch.num_rows}
+
+
+def _bytes(data, *_, **__) -> dict:
+    return {"bytes": len(data)}
+
+
+@spanned("engine.codec.encode", _rows)
 def serialize(batch: ColumnBatch, columns: Optional[Iterable[str]] = None
               ) -> bytes:
     """npz-framed columnar object (compressed; the ZSTD-Parquet stand-in)."""
@@ -101,6 +112,7 @@ def _align(n: int) -> int:
     return (n + _FRAME_ALIGN - 1) // _FRAME_ALIGN * _FRAME_ALIGN
 
 
+@spanned("engine.codec.encode", _rows)
 def serialize_frame(batch: ColumnBatch,
                     columns: Optional[Iterable[str]] = None,
                     compress: bool = False) -> bytes:
@@ -125,6 +137,7 @@ def serialize_frame(batch: ColumnBatch,
     return prefix + b"".join(payloads)
 
 
+@spanned("engine.codec.decode", _bytes)
 def deserialize_frame(data: bytes,
                       columns: Optional[Iterable[str]] = None) -> ColumnBatch:
     """Decode a frame; unrequested column buffers are never touched. Without
@@ -195,7 +208,8 @@ def deserialize(data: bytes, columns: Optional[Iterable[str]] = None
     Accepts both npz table objects and zero-copy shuffle frames."""
     if data[:4] == FRAME_MAGIC:
         return deserialize_frame(data, columns)
-    with np.load(io.BytesIO(data)) as z:
+    with span("engine.codec.decode", bytes=len(data)), \
+            np.load(io.BytesIO(data)) as z:
         names = list(z.files if columns is None else columns)
         return ColumnBatch({k: z[k] for k in names})
 

@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span, spanned
 from repro.engine import logical as engine_logical
 from repro.engine import operators
 from repro.engine import plans as engine_plans
@@ -90,6 +91,22 @@ from repro.engine.columnar import ColumnBatch
 from repro.kernels import hash_join as hj_kernel
 from repro.kernels import tpu
 from repro.kernels.segment_reduce import segment_reduce
+
+
+# ---------------------------------------------------------------------------
+# Device boundary
+# ---------------------------------------------------------------------------
+
+def _on_device(kernel: str, fn, *args, **kwargs):
+    """Call a jitted function or kernel and copy its outputs to host
+    numpy, inside one ``engine.device`` span: the time the host is
+    blocked on the chip, transfers included. ``h2d_bytes`` counts the
+    host arrays handed in, at the width the device receives them."""
+    h2d = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+              for a in jax.tree.leaves(args)
+              if isinstance(a, (np.ndarray, np.generic)))
+    with span("engine.device", kernel=kernel, h2d_bytes=h2d):
+        return jax.tree.map(np.asarray, fn(*args, **kwargs))
 
 
 # ---------------------------------------------------------------------------
@@ -274,7 +291,8 @@ class _MaskStage:
             n = len(next(iter(env.values())))
             cols, _ = _bounded_shape({k: env[k] for k in self.refs}, n,
                                      self._seen)
-            mask = np.asarray(self._fn(cols, _narrow_lits(lits)))[:n]
+            mask = _on_device("mask", self._fn, cols,
+                              _narrow_lits(lits))[:n]
         idx = np.flatnonzero(mask)
         return {k: v[idx] for k, v in env.items()}
 
@@ -347,8 +365,9 @@ class _ProjectStage:
         if self._fn is not None:
             cols, _ = _bounded_shape({k: env[k] for k in self.refs}, n,
                                      self._seen)
-            for name, v in self._fn(cols, _narrow_lits(lits)).items():
-                out[name] = np.asarray(v)[:n]
+            for name, v in _on_device("project", self._fn, cols,
+                                      _narrow_lits(lits)).items():
+                out[name] = v[:n]
         return {name: out[name] for name in self.order}
 
 
@@ -370,23 +389,6 @@ def _compile_segment(segment_json: str):
             stages.append(_ProjectStage(segment[i]["columns"]))
             i += 1
     return stages
-
-
-# Trace-cache observability (read by ``explain`` and the serving
-# metrics): lookups/hits of the canonical-keyed compiled-object caches.
-TRACE_CACHE_STATS = {"segment_lookups": 0, "segment_hits": 0,
-                     "tail_lookups": 0, "tail_hits": 0}
-
-
-def _counted(cache_fn, kind: str, *args):
-    """Call an lru-cached compile function, recording hit/miss. Fragments
-    execute serially per process, so the cache_info delta is race-free."""
-    before = cache_fn.cache_info().hits
-    out = cache_fn(*args)
-    TRACE_CACHE_STATS[f"{kind}_lookups"] += 1
-    if cache_fn.cache_info().hits > before:
-        TRACE_CACHE_STATS[f"{kind}_hits"] += 1
-    return out
 
 
 def _canon_json(ops: list[dict]) -> tuple[str, list]:
@@ -444,7 +446,7 @@ def _run_fused(batch: ColumnBatch, segment: list[dict]) -> ColumnBatch:
     # stage may consume wide integers produced by an earlier one).
     canon, lits = _canon_json(segment)
     env = {k: np.asarray(v) for k, v in batch.items()}
-    for stage in _counted(_compile_segment, "segment", canon):
+    for stage in _compile_segment(canon):
         env = stage.run(env, lits)
     return ColumnBatch(env)
 
@@ -887,11 +889,12 @@ class _FusedTail:
         if fn is None:
             fn = self._build_fn(final_sources, left_in, right_in, needs_pos)
             self._fns[key] = fn
-        res = fn(left_cols, lits_t, bkeys_pad, bpay_sorted, scalars, starts,
-                 np.int32(n), iters=iters, r=r)
-        assign = np.asarray(res[0])[:n]
-        derived = {name: v for name, v in res[1].items()}
-        pos = np.asarray(res[2])[:n] if needs_pos else None
+        res = _on_device("join_probe" if self.join else "tail", fn,
+                         left_cols, lits_t, bkeys_pad, bpay_sorted, scalars,
+                         starts, np.int32(n), iters=iters, r=r)
+        assign = res[0][:n]
+        derived = res[1]
+        pos = res[2][:n] if needs_pos else None
 
         order, counts = self._stable_partition(assign, r)
         out = self._gather_out(batch, bpay_out, sources_host, derived,
@@ -908,9 +911,10 @@ class _FusedTail:
         if cf is None:
             cf = self._build_count_fn()
             self._fns[("count",)] = cf
-        lo, counts = cf(left_cols[self.join["left_key"]], bkeys_pad,
-                        scalars, starts, np.int32(n), iters=iters)
-        counts = np.asarray(counts)
+        lo, counts = _on_device("join_count", cf,
+                                left_cols[self.join["left_key"]],
+                                bkeys_pad, scalars, starts, np.int32(n),
+                                iters=iters)
         prefix = np.zeros(len(counts) + 1, dtype=np.int64)
         np.cumsum(counts, dtype=np.int64, out=prefix[1:])
         total = int(prefix[-1])
@@ -934,13 +938,13 @@ class _FusedTail:
         if ef is None:
             ef = self._build_expand_fn(sources, left_in, right_in)
             self._fns[key] = ef
-        res = ef(left_cols, lits_t, bpay_sorted, np.asarray(lo),
-                 prefix.astype(np.int32), np.int32(total), r=r,
-                 n_out=n_out)
-        assign = np.asarray(res[0])[:total]
-        derived = {name: v for name, v in res[1].items()}
-        lsel = np.asarray(res[2])[:total]
-        rpos = np.asarray(res[3])[:total]
+        res = _on_device("join_expand", ef, left_cols, lits_t, bpay_sorted,
+                         lo, prefix.astype(np.int32), np.int32(total),
+                         r=r, n_out=n_out)
+        assign = res[0][:total]
+        derived = res[1]
+        lsel = res[2][:total]
+        rpos = res[3][:total]
 
         order, counts_p = self._stable_partition(assign, r)
         out = self._gather_out(batch, bpay_out, sources_host, derived,
@@ -961,7 +965,7 @@ def _run_tail(batch: ColumnBatch, segment: list[dict], partition):
     build = segment[0].get("build") if segment and \
         segment[0]["op"] == "hash_join" else None
     canon, lits = _canon_json([_strip_build(op) for op in segment])
-    tail = _counted(_compile_tail, "tail", canon, partition)
+    tail = _compile_tail(canon, partition)
     return tail.run(batch, build, lits)
 
 
@@ -1021,9 +1025,11 @@ def _run_hash_agg(batch: ColumnBatch, keys: list[str],
             for row, (_, col) in enumerate(group):
                 v = np.asarray(batch[col], dtype=np.float32)
                 vals[row, :n] = v[order] if order is not None else v
-            red = np.asarray(segment_reduce(vals, ids, num_segments=s_pad,
-                                            mode=mode,
-                                            interpret=tpu.interpret()))
+            # Called through the module's global name, which a caller
+            # may wrap to count the kernel's work.
+            red = _on_device("segment_reduce", segment_reduce, vals, ids,
+                             num_segments=s_pad, mode=mode,
+                             interpret=tpu.interpret())
             for row, (name, _) in enumerate(group):
                 out[name] = red[row, :n_groups].astype(np.float64)
     for name, fn, _ in aggs:
@@ -1038,6 +1044,7 @@ def _run_hash_agg(batch: ColumnBatch, keys: list[str],
 # Pipeline driver
 # ---------------------------------------------------------------------------
 
+@spanned("engine.ops")
 def run_pipeline_jit(batch: ColumnBatch, ops: list[dict]) -> ColumnBatch:
     """Execute a pipeline spec with the compiled backend. Result-compatible
     with ``operators.run_pipeline_ops`` (modulo float32 accumulation)."""
@@ -1101,6 +1108,7 @@ def _fusable_tail_start(ops: list[dict]) -> int:
     return t
 
 
+@spanned("engine.ops")
 def run_pipeline_partition(batch: ColumnBatch, ops: list[dict],
                            key_col: str, partitions: int,
                            backend: str = "numpy") -> list[ColumnBatch]:
@@ -1162,6 +1170,7 @@ def run_pipeline(batch: ColumnBatch, ops: list[dict],
     return fn(batch, ops)
 
 
+@spanned("engine.ops")
 def run_pipeline_collect(batch: ColumnBatch, ops: list[dict],
                          backend: str = "numpy") -> ColumnBatch:
     """Execute a COLLECT fragment's pipeline spec.
@@ -1242,7 +1251,7 @@ class CompiledPlanCache:
 
     def stats(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,
-                "entries": len(self._entries), **TRACE_CACHE_STATS}
+                "entries": len(self._entries)}
 
     def clear(self) -> None:
         self._entries.clear()

@@ -28,6 +28,7 @@ from repro.core.scheduler import Fragment, Stage, StageScheduler, StragglerPolic
 from repro.core.storage_service import (KV_MEMORY_PROFILE, KVStore,
                                         LatencyModel, ObjectStore,
                                         RequestStats, S3_STANDARD_PROFILE)
+from repro.core.spans import spanned
 from repro.engine import columnar, optimizer, worker
 from repro.engine import compile as engine_compile
 from repro.engine.columnar import ColumnBatch
@@ -236,6 +237,7 @@ class Coordinator:
                              stats_before, shape_hash, cache_hit,
                              kv_stats_before=kv_stats_before)
 
+    @spanned("engine.finalize")
     def finalize(self, plan: QueryPlan, query_id: str,
                  frag_counts: dict[str, int], results: dict,
                  stats_before: RequestStats, shape_hash: str = "",
@@ -300,7 +302,12 @@ class Coordinator:
                     "duration": r.end_t - r.start_t,
                     "workers": r.worker_count,
                     "retried": r.retried_fragments,
-                    "speculative": getattr(r, "speculative_launched", 0)}
+                    "speculative": getattr(r, "speculative_launched", 0),
+                    # Host seconds its fragments took, from their spans;
+                    # the figures above are the scheduler's model.
+                    "measured_s": sum(getattr(m, "wall_s", 0.0)
+                                      for m in r.results
+                                      if m is not None)}
                 for n, r in results.items()},
             request_stats=merged_stats, peak_workers=max(
                 r.worker_count for r in results.values()),

@@ -75,9 +75,10 @@ def format_adaptive(result) -> str:
     """Render a ``QueryResult``'s adaptive-execution section: the
     ``adaptive:`` decision lines recorded at stage boundaries by
     ``engine.adaptive``, the revision/speculation counters, and per-stage
-    timings. Under the static coordinator the section shows zero
-    revisions — the before/after transcript in docs/ARCHITECTURE.md is
-    exactly this rendering."""
+    timings: the scheduler's modelled start, end and duration beside the
+    host seconds its fragments measured. Under the static coordinator
+    the section shows zero revisions — the before/after transcript in
+    docs/ARCHITECTURE.md is this rendering."""
     lines = ["adaptive execution", "=================="]
     lines += [f"- {ln}" for ln in result.adaptive_trace] \
         or ["- (no revisions)"]
@@ -90,11 +91,13 @@ def format_adaptive(result) -> str:
             f"FAILED: [{failure['kind']}] stage {failure['stage']!r} "
             f"after {failure['attempts']} attempt(s) — "
             f"{failure['message']}")
-    lines.append("stage timings")
+    lines.append("stage timings (start, end, duration: modelled; "
+                 "measured: host seconds of the stage's fragments)")
     for name, m in result.stage_metrics.items():
         lines.append(f"  {name}: start={m['start']:.3f}s "
                      f"end={m['end']:.3f}s "
                      f"duration={m['duration']:.3f}s "
+                     f"measured={m.get('measured_s', 0.0):.3f}s "
                      f"workers={m['workers']} "
                      f"speculative={m.get('speculative', 0)}")
     return "\n".join(lines)

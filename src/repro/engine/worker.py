@@ -41,6 +41,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core import memory as core_memory
+from repro.core.spans import span
 from repro.core.storage_service import ObjectStore
 from repro.engine import columnar, compile as engine_compile, operators, \
     spill
@@ -152,6 +153,8 @@ class FragmentMetrics:
     mem_peak_bytes: int = 0
     mem_overcommit_bytes: int = 0
     mem_cap_bytes: int = 0
+    # Host wall time of the fragment, from its ``engine.fragment`` span.
+    wall_s: float = 0.0
 
 
 class ShuffleRegistry:
@@ -292,7 +295,8 @@ def _read_side(store: ObjectStore, keys: list[str], columns,
         metrics.read_requests += 1
         metrics.read_bytes += len(data)
         batches.append(columnar.deserialize(data, columns))
-    batch = ColumnBatch.concat(batches)
+    with span("engine.fragment.assemble"):
+        batch = ColumnBatch.concat(batches)
     metrics.rows_in += batch.num_rows
     return batch
 
@@ -420,11 +424,16 @@ def execute_fragment(store: ObjectStore, spec: FragmentSpec,
                                       spec.attempt, partitions)
 
     metrics = FragmentMetrics()
-    if spec.memory_budget is not None:
-        return _execute_out_of_core(store, spec, metrics, registry,
-                                    tier_store, kill_after=kill_after)
-    return _execute_in_memory(store, spec, metrics, registry, tier_store,
-                              chaos=chaos, kill_after=kill_after)
+    with span("engine.fragment", qid=spec.query_id, pipeline=spec.pipeline,
+              fragment=spec.fragment, attempt=spec.attempt) as sp:
+        if spec.memory_budget is not None:
+            _execute_out_of_core(store, spec, metrics, registry,
+                                 tier_store, kill_after=kill_after)
+        else:
+            _execute_in_memory(store, spec, metrics, registry, tier_store,
+                               chaos=chaos, kill_after=kill_after)
+    metrics.wall_s = sp.seconds
+    return metrics
 
 
 def _execute_in_memory(store: ObjectStore, spec: FragmentSpec,

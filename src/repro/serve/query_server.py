@@ -32,7 +32,9 @@ from typing import Optional, Union
 
 import numpy as np
 
+from repro.core import host_memory
 from repro.core.scheduler import MultiQueryScheduler, QueryJob, StragglerPolicy
+from repro.core.spans import span, spanned
 from repro.core.storage_service import ObjectStore
 from repro.core.token_bucket import AdmissionBucket, AdmissionConfig
 from repro.engine import compile as engine_compile
@@ -230,6 +232,9 @@ class QueryServer:
                  result_cache: bool = True, max_workers: int = 1024,
                  rng_seed: int = 0, chaos=None,
                  speculation_headroom: int = 0, stage_retries: int = 2):
+        # Column arrays come and go by the hundred MB a fragment: keep
+        # their cost in page faults the same from one process to the next.
+        host_memory.steady_heap()
         self.store = store
         self.worker_budget = worker_budget
         self.coordinator = Coordinator(store, mode=mode, backend=backend,
@@ -271,6 +276,8 @@ class QueryServer:
                         etags[k] = self.store.etag(k)
         return etags
 
+    @spanned("engine.serve", lambda self, requests, *_, **__: {
+        "requests": len(requests)})
     def serve(self, requests: list, interleave: bool = True) -> ServeReport:
         reqs = [r if isinstance(r, QueryRequest) else QueryRequest(r)
                 for r in requests]
@@ -279,55 +286,59 @@ class QueryServer:
         prepared = []          # (req, plan, qid, job|None, ctx)
         plan_hits = plan_misses = result_hits = 0
         for req in sorted(reqs, key=lambda r: r.submit_t):
-            plan = self._lower(req.query)
-            qid = f"{plan.name}-{self._seq}"
-            self._seq += 1
-            shape_hash, plan_hit = "", False
-            if coord.backend == "jit":
-                shape_hash, plan_hit = engine_compile.PLAN_CACHE.lookup(plan)
-                plan_hits += plan_hit
-                plan_misses += not plan_hit
-            cache_key = entry = None
-            if self.result_cache is not None:
-                cache_key = ResultCache.key_for(plan)
-                entry = self.result_cache.lookup(cache_key)
-            if entry is not None:
-                # Replayed from cache: no fragments, no pool, no
-                # admission cost — the query is served at submit time.
-                result_hits += 1
-                merged = coord._merge_collect(
-                    entry["query_id"], plan.pipelines[-1],
-                    entry["n_frags"])
-                prepared.append((req, plan, qid, None, {
-                    "merged": merged, "shape_hash": shape_hash,
+            with span("engine.plan") as sp:
+                plan = self._lower(req.query)
+                qid = f"{plan.name}-{self._seq}"
+                self._seq += 1
+                sp.qid = qid
+                shape_hash, plan_hit = "", False
+                if coord.backend == "jit":
+                    shape_hash, plan_hit = \
+                        engine_compile.PLAN_CACHE.lookup(plan)
+                    plan_hits += plan_hit
+                    plan_misses += not plan_hit
+                cache_key = entry = None
+                if self.result_cache is not None:
+                    cache_key = ResultCache.key_for(plan)
+                    entry = self.result_cache.lookup(cache_key)
+                if entry is not None:
+                    # Replayed from cache: no fragments, no pool, no
+                    # admission cost — the query is served at submit time.
+                    result_hits += 1
+                    merged = coord._merge_collect(
+                        entry["query_id"], plan.pipelines[-1],
+                        entry["n_frags"])
+                    prepared.append((req, plan, qid, None, {
+                        "merged": merged, "shape_hash": shape_hash,
+                        "plan_hit": plan_hit}))
+                    continue
+                plan.validate()
+                stats_before = dataclasses.replace(self.store.stats)
+                kv_stats_before = dataclasses.replace(coord.kv_store.stats)
+                table_etags = self._table_etags(plan)
+                registry = worker.ShuffleRegistry()
+                stages, frag_counts = coord.compile_stages(plan, qid, registry)
+                job = QueryJob(job_id=qid, stages=stages,
+                               submit_t=req.submit_t, tenant=req.tenant)
+                prepared.append((req, plan, qid, job, {
+                    "frag_counts": frag_counts, "registry": registry,
+                    "stats_before": stats_before,
+                    "kv_stats_before": kv_stats_before,
+                    "table_etags": table_etags,
+                    "cache_key": cache_key, "shape_hash": shape_hash,
                     "plan_hit": plan_hit}))
-                continue
-            plan.validate()
-            stats_before = dataclasses.replace(self.store.stats)
-            kv_stats_before = dataclasses.replace(coord.kv_store.stats)
-            table_etags = self._table_etags(plan)
-            registry = worker.ShuffleRegistry()
-            stages, frag_counts = coord.compile_stages(plan, qid, registry)
-            job = QueryJob(job_id=qid, stages=stages,
-                           submit_t=req.submit_t, tenant=req.tenant)
-            prepared.append((req, plan, qid, job, {
-                "frag_counts": frag_counts, "registry": registry,
-                "stats_before": stats_before,
-                "kv_stats_before": kv_stats_before,
-                "table_etags": table_etags,
-                "cache_key": cache_key, "shape_hash": shape_hash,
-                "plan_hit": plan_hit}))
 
         jobs = [job for _, _, _, job, _ in prepared if job is not None]
-        if interleave:
-            if jobs:
-                self.scheduler.run_jobs(jobs, admitter)
-        else:
-            cursor = 0.0
-            for job in jobs:          # already in submit order
-                job.submit_t = max(job.submit_t, cursor)
-                self.scheduler.run_jobs([job], admitter)
-                cursor = job.finish_t
+        with span("engine.schedule"):
+            if interleave:
+                if jobs:
+                    self.scheduler.run_jobs(jobs, admitter)
+            else:
+                cursor = 0.0
+                for job in jobs:          # already in submit order
+                    job.submit_t = max(job.submit_t, cursor)
+                    self.scheduler.run_jobs([job], admitter)
+                    cursor = job.finish_t
 
         served = []
         for req, plan, qid, job, ctx in prepared:

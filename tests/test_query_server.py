@@ -102,9 +102,7 @@ def test_compiled_plan_cache_lru_and_stats():
     assert not cache.contains(ha)
     _, hit = cache.lookup(a)
     assert not hit
-    stats = cache.stats()
-    for key in ("hits", "misses", "entries", "segment_hits", "tail_hits"):
-        assert key in stats
+    assert cache.stats() == {"hits": 1, "misses": 4, "entries": 2}
 
 
 # ---------------------------------------------------------------------------
@@ -356,3 +354,30 @@ def test_bench_profile_stale_section_warns_once(tmp_path):
         assert bench_profile.section(
             "anything", path=tmp_path / "absent.json") == {}
     bench_profile.clear_cache()
+
+
+def test_server_fixes_heap_thresholds(monkeypatch):
+    """Building a server fixes the C library's heap thresholds, after
+    which a 20 MiB block comes from the heap, not a fresh mapping."""
+    import ctypes
+
+    from repro.core import host_memory
+    calls = []
+    monkeypatch.setattr(host_memory, "steady_heap",
+                        lambda: calls.append(1) or True)
+    QueryServer(ObjectStore(), result_cache=False)
+    assert calls == [1]
+    monkeypatch.undo()
+
+    class MallInfo2(ctypes.Structure):
+        _fields_ = [(f, ctypes.c_size_t) for f in (
+            "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks",
+            "fsmblks", "uordblks", "fordblks", "keepcost")]
+
+    libc = ctypes.CDLL(None)
+    libc.mallinfo2.restype = MallInfo2
+    assert host_memory.steady_heap()
+    before = libc.mallinfo2().hblkhd
+    block = np.ones(20 << 20, dtype=np.uint8)
+    assert libc.mallinfo2().hblkhd - before < 20 << 20
+    assert int(block.sum()) == 20 << 20
