@@ -10,7 +10,13 @@ dictionaries kept in ``DICTIONARIES`` (vectorized execution stays numeric).
 Two on-the-wire formats coexist:
 
 * npz (``serialize``/zlib) — the ZSTD-Parquet stand-in for *base tables*,
-  where storage cost matters more than encode speed.
+  where storage cost matters more than encode speed. Decoding reads the
+  zip directory once and inflates each requested member in one
+  ``zlib.decompress`` call straight from the object's bytes, checks its
+  CRC-32 against the directory, and wraps the result as a read-only
+  ``np.frombuffer`` view. Where 2 or more members are requested they
+  inflate concurrently on a small thread pool (zlib releases the GIL).
+  ``DECODE_STATS`` counts the members of each path.
 * ``FRAME_MAGIC`` frames (``serialize_frame``) — a zero-copy format for
   *shuffle intermediates*: a JSON header plus raw little-endian column
   buffers. Decoding a column is a single ``np.frombuffer`` view into the
@@ -18,14 +24,21 @@ Two on-the-wire formats coexist:
   them. Per-column zlib compression is available behind a flag for
   network-bound deployments.
 
-``deserialize`` sniffs the magic and accepts either format.
+``deserialize`` sniffs the magic and accepts either format. Decoded
+columns of either format may be read-only views: a consumer that writes
+into one copies it first.
 """
 from __future__ import annotations
 
 import io
 import json
+import math
+import os
 import struct
+import threading
+import zipfile
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Optional
 
 import numpy as np
@@ -183,9 +196,6 @@ def schema_widths(data: bytes) -> dict[str, int]:
         header = json.loads(data[9:4 + 5 + header_len])
         return {name: np.dtype(dtype_str).itemsize
                 for name, dtype_str, *_ in header["cols"]}
-    import zipfile
-    header_readers = {(1, 0): np.lib.format.read_array_header_1_0,
-                      (2, 0): np.lib.format.read_array_header_2_0}
     out: dict[str, int] = {}
     with zipfile.ZipFile(io.BytesIO(data)) as zf:
         for info in zf.infolist():
@@ -193,8 +203,7 @@ def schema_widths(data: bytes) -> dict[str, int]:
             if not name.endswith(".npy"):
                 continue
             with zf.open(info) as f:
-                version = np.lib.format.read_magic(f)
-                reader = header_readers.get(version)
+                reader = _NPY_HEADER_READERS.get(np.lib.format.read_magic(f))
                 if reader is None:   # unknown .npy format revision
                     continue
                 _shape, _fortran, dtype = reader(f)
@@ -208,10 +217,91 @@ def deserialize(data: bytes, columns: Optional[Iterable[str]] = None
     Accepts both npz table objects and zero-copy shuffle frames."""
     if data[:4] == FRAME_MAGIC:
         return deserialize_frame(data, columns)
-    with span("engine.codec.decode", bytes=len(data)), \
-            np.load(io.BytesIO(data)) as z:
-        names = list(z.files if columns is None else columns)
-        return ColumnBatch({k: z[k] for k in names})
+    with span("engine.codec.decode", bytes=len(data)):
+        return _deserialize_npz(data, columns)
+
+
+# ---------------------------------------------------------------------------
+# npz decoding (base tables)
+# ---------------------------------------------------------------------------
+
+_NPY_HEADER_READERS = {(1, 0): np.lib.format.read_array_header_1_0,
+                       (2, 0): np.lib.format.read_array_header_2_0}
+# A zip local file header: signature, 22 fixed bytes, then the lengths of
+# the name and extra field that precede the member's compressed bytes.
+_LOCAL_HEADER = struct.Struct("<4s22xHH")
+
+# Inflating threads: as many as this process may run on, at most 16.
+_POOL_WORKERS = min(len(os.sched_getaffinity(0))
+                    if hasattr(os, "sched_getaffinity")
+                    else os.cpu_count() or 1, 16)
+_pool: Optional[ThreadPoolExecutor] = None
+_lock = threading.Lock()
+
+# Process-global observability: members decoded on the pool (2 or more
+# requested from one object) and in the caller's thread (one requested).
+DECODE_STATS = {"members_concurrent": 0, "members_serial": 0}
+
+
+def _inflate_pool() -> ThreadPoolExecutor:
+    global _pool
+    with _lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(_POOL_WORKERS,
+                                       thread_name_prefix="columnar-inflate")
+        return _pool
+
+
+def _decode_member(data, info: zipfile.ZipInfo) -> np.ndarray:
+    """One npz member as a read-only array, inflated in one call from
+    ``data`` (copied, if stored) and CRC-checked. It may run on a pool
+    thread, so it opens no span."""
+    magic, name_len, extra_len = _LOCAL_HEADER.unpack_from(
+        data, info.header_offset)
+    if magic != b"PK\x03\x04":
+        raise zipfile.BadZipFile(
+            f"Bad magic number for file header {info.filename!r}")
+    start = info.header_offset + _LOCAL_HEADER.size + name_len + extra_len
+    view = memoryview(data)[start:start + info.compress_size]
+    if info.compress_type == zipfile.ZIP_DEFLATED:
+        raw = zlib.decompress(view, -15, info.file_size)
+    elif info.compress_type == zipfile.ZIP_STORED:
+        raw = bytes(view)
+    else:
+        raise NotImplementedError(
+            f"compression method {info.compress_type} of {info.filename!r}")
+    if len(raw) != info.file_size or zlib.crc32(raw) != info.CRC:
+        raise zipfile.BadZipFile(f"Bad CRC-32 for file {info.filename!r}")
+    f = io.BytesIO(raw)
+    version = np.lib.format.read_magic(f)
+    reader = _NPY_HEADER_READERS.get(version)
+    if reader is None:
+        raise ValueError(f"unsupported .npy format version {version} "
+                         f"in {info.filename!r}")
+    shape, fortran, dtype = reader(f)
+    if dtype.hasobject:
+        raise ValueError("Object arrays cannot be loaded when "
+                         "allow_pickle=False")
+    flat = np.frombuffer(raw, dtype, math.prod(shape), f.tell())
+    return flat.reshape(shape[::-1]).T if fortran else flat.reshape(shape)
+
+
+def _deserialize_npz(data, columns: Optional[Iterable[str]]) -> ColumnBatch:
+    with zipfile.ZipFile(io.BytesIO(data)) as zf:
+        infos = {i.filename: i for i in zf.infolist()}
+    names = ([n[:-4] for n in infos if n.endswith(".npy")]
+             if columns is None else list(columns))
+    members = [infos[n + ".npy"] for n in names]   # missing -> KeyError
+    concurrent = len(members) > 1
+    if concurrent:
+        arrays = list(_inflate_pool().map(
+            lambda info: _decode_member(data, info), members))
+    else:
+        arrays = [_decode_member(data, info) for info in members]
+    with _lock:
+        path = "members_concurrent" if concurrent else "members_serial"
+        DECODE_STATS[path] += len(arrays)
+    return ColumnBatch(dict(zip(names, arrays)))
 
 
 # Dictionary encodings for TPC string columns (kept numeric in batches).
