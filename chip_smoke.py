@@ -129,14 +129,7 @@ def engine_phase(seed: int, lineitem_rows: int = 6_000_000,
         t: b.num_rows for t, b in full.items()},
         "seconds": time.perf_counter() - t0})
 
-    # Session windows are fragment-local, so BB-Q3 runs one fragment per
-    # clickstream object and its reference is the per-object sum.
-    bb_views: dict[int, int] = {}
-    for part in parts["clickstreams"]:
-        for item, n in queries.bb_q3_reference(part, full["item"]).items():
-            bb_views[item] = bb_views.get(item, 0) + n
-    bb_plan = queries.bb_q3_plan(keys["item"][0])
-    bb_plan.pipelines[0].fragments = len(keys["clickstreams"])
+    bb_views = queries.bb_q3_reference(full["clickstreams"], full["item"])
     items = np.asarray(sorted(bb_views), np.int64)
     cases = [
         ("q1", queries.q1_logical(), ["l_returnflag", "l_linestatus"],
@@ -149,7 +142,7 @@ def engine_phase(seed: int, lineitem_rows: int = 6_000_000,
         ("q12", queries.q12_logical(), ["l_shipmode"],
          lambda: queries.q12_reference(full["lineitem"], full["orders"]),
          full["lineitem"].num_rows + full["orders"].num_rows),
-        ("bb_q3", bb_plan, ["viewed_item"],
+        ("bb_q3", queries.bb_q3_logical(keys["item"][0]), ["viewed_item"],
          lambda: ColumnBatch({"viewed_item": items, "views": np.asarray(
              [bb_views[int(i)] for i in items], np.float64)}),
          full["clickstreams"].num_rows),

@@ -32,12 +32,24 @@ ring has pushed out. Every name starts with ``engine.``:
     engine.codec.decode  columnar deserialization            bytes
     engine.codec.encode  columnar serialization              rows
     engine.ops         the fused backend's drivers (host glue)
+    engine.udf         one UDF call, on either backend        udf, rows_in,
+                                                              rows_out
     engine.device      a jitted call or kernel and the copy   kernel,
                        of its outputs to the host             h2d_bytes
     engine.finalize    the coordinator's merge of a query's results
 
 A layer's time is the self time of its spans: their duration less
 what their child spans cover.
+
+``count(name, n)`` adds to a program counter: to the process total in
+``COUNTERS``, which a caller reads before and after a stretch of work,
+and to the attribute ``name`` of the innermost open span, so that a
+reader of the ring can sum it over the spans of a window. The counters:
+
+    exchange_bytes            shuffle frame bytes a fragment puts
+    session_window.calls      calls of the traced session window
+    session_window.rows       rows they were handed (padding left out)
+    session_window.purchases  qualifying purchases they found
 """
 from __future__ import annotations
 
@@ -78,6 +90,9 @@ class SpanRing:
 
 
 RING = SpanRing()
+
+COUNTERS: collections.Counter = collections.Counter()
+_counters_lock = threading.Lock()
 
 _ids = itertools.count(1)
 
@@ -133,6 +148,17 @@ class Span:
         _stack.open.pop()
         RING.append(self)
         return False
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the program counter ``name`` and to the attribute of
+    the same name of this thread's innermost open span."""
+    with _counters_lock:
+        COUNTERS[name] += n
+    stack = _stack.open
+    if stack:
+        attrs = stack[-1].attrs
+        attrs[name] = attrs.get(name, 0) + n
 
 
 def span(name: str, qid: Optional[str] = None, **attrs) -> Span:

@@ -43,8 +43,12 @@ specs into fused kernels:
   stacked so all same-mode aggregates reduce in a single kernel launch —
   interpret mode on CPU, Mosaic on TPU, like the kernels in
   ``kernels/ops.py``.
-* ``udf`` operators fall back to the numpy implementations (they carry
-  non-JSON broadcast arrays and data-dependent shapes).
+* ``udf`` operators run the UDF's traced implementation where
+  ``_TRACED_UDFS`` has one, through the device boundary: TPCx-BB Q3's
+  session window is one jitted program (``kernels.session_window``) over
+  the fragment's whole user sessions. A UDF with no traced
+  implementation, or a batch outside its int32 program, runs the numpy
+  function (``operators``), which stays the reference.
 
 Fragments call ``run_pipeline_partition`` so the shuffle partition fuses
 into the trailing compiled segment on the jit backend; the numpy backend
@@ -83,12 +87,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.spans import span, spanned
 from repro.engine import logical as engine_logical
 from repro.engine import operators
 from repro.engine import plans as engine_plans
 from repro.engine.columnar import ColumnBatch
 from repro.kernels import hash_join as hj_kernel
+from repro.kernels import session_window as sw_kernel
 from repro.kernels import tpu
 from repro.kernels.segment_reduce import segment_reduce
 
@@ -402,9 +408,11 @@ _INT32_MIN = np.iinfo(np.int32).min
 # The int32 fallback warning fires once per process: silent per-fragment
 # warnings would flood a query's log, silence would hide that a "jit"
 # query is quietly running its joins interpreted. The counter counts every
-# such fallback (per fragment call), for callers that must see each one.
+# such fallback (per fragment call), for callers that must see each one;
+# ``int32_udf`` counts UDF calls whose traced implementation refused the
+# batch and ran numpy instead.
 _INT32_FALLBACK_WARNED = False
-FALLBACK_STATS = {"int32_join": 0}
+FALLBACK_STATS = {"int32_join": 0, "int32_udf": 0}
 
 
 def _warn_int32_fallback(detail: str) -> None:
@@ -1041,6 +1049,85 @@ def _run_hash_agg(batch: ColumnBatch, keys: list[str],
 
 
 # ---------------------------------------------------------------------------
+# UDFs
+# ---------------------------------------------------------------------------
+
+def _fits(v: np.ndarray, below: int) -> bool:
+    return v.size == 0 or (v.dtype.kind in "iu" and int(v.min()) >= 0
+                           and int(v.max()) < below)
+
+
+def _session_window_udf(batch: ColumnBatch, *, order_by, item_categories,
+                        target_category, views=5, seconds=864000):
+    """``operators.clicks_before_purchase`` through
+    ``kernels.session_window``: the same rows, with one row per viewed
+    item whose ``n`` is its count. None where a column does not fit the
+    int32 program, or the declared order does not name all four
+    columns."""
+    names = tuple(order_by or ())
+    if sorted(names) != sorted(sw_kernel.COLUMNS):
+        return None
+    cats = np.asarray(item_categories)
+    cols = {c: np.asarray(batch[c]) for c in sw_kernel.COLUMNS}
+    limit = np.iinfo(np.int32).max
+    if not (_fits(cols[sw_kernel.USER], limit)
+            and _fits(cols[sw_kernel.TSTAMP], limit)
+            and _fits(cols[sw_kernel.ITEM], len(cats))
+            and _fits(cols[sw_kernel.CTYPE], 127)
+            and _fits(cats, limit) and 0 <= target_category < limit
+            and 0 <= seconds < limit):
+        return None
+    n = batch.num_rows
+    n_pad = _pow2(n)
+    args = []
+    for i, c in enumerate(names):
+        v = cols[c].astype(np.int8 if c == sw_kernel.CTYPE else np.int32)
+        # Padding rows sort last: the first key's largest value.
+        fill = np.iinfo(v.dtype).max if i == 0 else 0
+        args.append(np.concatenate([v, np.full(n_pad - n, fill, v.dtype)]))
+    # Room for every purchase, of any category, so that the shape does
+    # not depend on the target.
+    room = _pow2(max(1, int(np.count_nonzero(
+        cols[sw_kernel.CTYPE] == sw_kernel.PURCHASE))))
+    counts, purchases = _on_device(
+        "session_window", sw_kernel.session_window, tuple(args),
+        np.int32(n), cats.astype(np.int32), np.int32(target_category),
+        np.int32(seconds), names=names, views=views, max_purchases=room)
+    spans.count("session_window.calls")
+    spans.count("session_window.rows", n)
+    spans.count("session_window.purchases", int(purchases))
+    viewed = np.flatnonzero(counts)
+    return {"viewed_item": viewed.astype(np.int64),
+            "n": counts[viewed].astype(np.int64)}
+
+
+# UDFs with a traced implementation, by name: ``fn(batch, order_by=...,
+# **kwargs)`` returns the UDF's output columns, or None where the batch
+# does not fit its program (the numpy UDF then runs instead).
+_TRACED_UDFS = {"clicks_before_purchase": _session_window_udf}
+
+
+def _run_udf(batch: ColumnBatch, op: dict) -> ColumnBatch:
+    """A ``udf`` op: the UDF's traced implementation where
+    ``_TRACED_UDFS`` has one and the batch fits its int32 program, else
+    its numpy function; either way inside one ``engine.udf`` span, the
+    device call nested in it."""
+    name, kwargs = op["name"], op.get("kwargs", {})
+    order_by = op.get("order_by")
+    traced = _TRACED_UDFS.get(name)
+    with span("engine.udf", udf=name, rows_in=batch.num_rows) as sp:
+        out = None
+        if traced is not None and batch.num_rows:
+            out = traced(batch, order_by=order_by, **kwargs)
+            if out is None:
+                FALLBACK_STATS["int32_udf"] += 1
+        out = operators.udf_reference(batch, name, order_by, **kwargs) \
+            if out is None else ColumnBatch(out)
+        sp.attrs["rows_out"] = out.num_rows
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Pipeline driver
 # ---------------------------------------------------------------------------
 
@@ -1070,8 +1157,7 @@ def run_pipeline_jit(batch: ColumnBatch, ops: list[dict]) -> ColumnBatch:
             batch = _run_hash_agg(batch, ops[i]["keys"], ops[i]["aggs"])
             i += 1
         elif kind == "udf":
-            batch = operators.op_udf(batch, ops[i]["name"],
-                                     **ops[i].get("kwargs", {}))
+            batch = _run_udf(batch, ops[i])
             i += 1
         else:
             raise ValueError(f"unknown operator {kind!r}")

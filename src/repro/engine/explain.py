@@ -48,7 +48,9 @@ def _fmt_op(op: dict) -> str:
         return (f"hash_join probe.{op['left_key']} = "
                 f"build.{op['right_key']}")
     if kind == "udf":
-        return f"udf {op['name']} kwargs={op.get('kwargs', {})}"
+        dist = "" if op.get("partition_by") is None else (
+            f" on hash({op['partition_by']}) sorted {op['order_by']}")
+        return f"udf {op['name']} kwargs={op.get('kwargs', {})}{dist}"
     return repr(op)
 
 

@@ -335,6 +335,12 @@ class Udf:
     kwargs: dict
     broadcast: Optional[dict] = None
     output_columns: Optional[list[str]] = None   # declared schema, if known
+    # Required distribution: every row with one ``partition_by`` value
+    # reaches the same UDF call, its rows sorted by ``order_by`` (column
+    # names, most significant first). The optimizer inserts a hash
+    # exchange on the key unless the input is already partitioned by it.
+    partition_by: Optional[str] = None
+    order_by: Optional[list[str]] = None
 
 
 @dataclasses.dataclass
@@ -490,13 +496,25 @@ class LogicalPlan:
 
     def map_udf(self, name: str, kwargs: Optional[dict] = None,
                 broadcast: Optional[dict] = None,
-                output_columns: Optional[list[str]] = None) -> "LogicalPlan":
+                output_columns: Optional[list[str]] = None,
+                partition_by: Optional[str] = None,
+                order_by: Optional[list[str]] = None) -> "LogicalPlan":
         """Apply a registered UDF (``operators.register_udf``) as a map
         stage. ``broadcast`` declares side-input columns loaded from the
         store at runtime; ``output_columns`` declares the UDF's output
-        schema so downstream pruning/validation can see through it."""
+        schema so downstream pruning/validation can see through it.
+        ``partition_by`` and ``order_by`` declare the distribution the
+        UDF requires (a reducer over whole groups, such as a user's
+        sessions): each call sees every row of its ``partition_by``
+        values, sorted by the ``order_by`` columns."""
+        if order_by is not None and partition_by is None:
+            raise LogicalError("map_udf order_by needs a partition_by key")
+        if partition_by is not None:
+            order_by = [c._colname("order_by") if isinstance(c, Expr)
+                        else c for c in (order_by or [partition_by])]
         return LogicalPlan(Udf(self.node, name, dict(kwargs or {}),
-                               broadcast, output_columns))
+                               broadcast, output_columns,
+                               partition_by, order_by))
 
     def collect(self, name: str = "query",
                 shuffle_partitions: Optional[int] = None) -> LogicalQuery:
@@ -575,6 +593,9 @@ def format_node(node, indent: int = 0) -> str:
                 + format_node(node.child, indent + 1))
     if isinstance(node, Udf):
         out = f" -> {node.output_columns}" if node.output_columns else ""
+        if node.partition_by is not None:
+            out = (f" distributed hash({node.partition_by}) sorted "
+                   f"{node.order_by}{out}")
         return (f"{pad}Udf[{node.name}]{out}\n"
                 + format_node(node.child, indent + 1))
     return f"{pad}{node!r}"

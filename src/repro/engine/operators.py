@@ -7,10 +7,11 @@ plans in JSON format [36]).
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Optional
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.engine.columnar import ColumnBatch
 
 # ---------------------------------------------------------------------------
@@ -251,7 +252,10 @@ def radix_partition(batch: ColumnBatch, key_col: str, partitions: int
     return [b for _, b in radix_partition_iter(batch, key_col, partitions)]
 
 
-# UDF registry (TPCx-BB Q3 style map-side session analysis).
+# UDF registry (TPCx-BB Q3 style session analysis). A UDF is a numpy
+# function over a ColumnBatch, the interpreted reference; the jit backend
+# runs a traced implementation in its place where it has one
+# (``compile._TRACED_UDFS``).
 _UDFS: dict[str, Callable] = {}
 
 
@@ -262,36 +266,62 @@ def register_udf(name: str):
     return deco
 
 
-def op_udf(batch: ColumnBatch, name: str, **kwargs) -> ColumnBatch:
+def udf_reference(batch: ColumnBatch, name: str,
+                  order_by: Optional[list[str]] = None,
+                  **kwargs) -> ColumnBatch:
+    """The numpy UDF over the batch, its rows first sorted (stably) by
+    ``order_by``, most significant column first, where the op declares
+    an order."""
+    if order_by and batch.num_rows:
+        order = np.lexsort([np.asarray(batch[c])
+                            for c in reversed(order_by)])
+        batch = ColumnBatch({k: np.asarray(v)[order]
+                             for k, v in batch.items()})
     return _UDFS[name](batch, **kwargs)
+
+
+def op_udf(batch: ColumnBatch, name: str, order_by: Optional[list[str]] = None,
+           **kwargs) -> ColumnBatch:
+    with span("engine.udf", udf=name, rows_in=batch.num_rows) as sp:
+        out = udf_reference(batch, name, order_by, **kwargs)
+        sp.attrs["rows_out"] = out.num_rows
+    return out
+
+
+VIEW, PURCHASE = 0, 2                      # wcs_click_type
 
 
 @register_udf("clicks_before_purchase")
 def clicks_before_purchase(batch: ColumnBatch, *, item_categories: np.ndarray,
-                           target_category: int, window: int = 5
-                           ) -> ColumnBatch:
-    """TPCx-BB Q3 core: for each purchase of an item in the target category,
-    emit the item_sks viewed in the preceding ``window`` clicks of the same
-    user session (sorted by user, date, time)."""
+                           target_category: int, views: int = 5,
+                           seconds: int = 864000) -> ColumnBatch:
+    """TPCx-BB Q3's reducer over one partition of whole user sessions,
+    its rows sorted by (user, tstamp, item, click type): for each purchase
+    (``wcs_click_type`` 2) of an item in ``target_category``, emit the
+    items of the user's last ``views`` views (click type 0) before it
+    whose ``tstamp`` is at most ``seconds`` earlier. A view keeps
+    counting for every later purchase whose window holds it."""
     if batch.num_rows == 0:
         return ColumnBatch({"viewed_item": np.asarray([], dtype=np.int64),
                             "n": np.asarray([], dtype=np.int64)})
-    order = np.lexsort((batch["wcs_click_time_sk"], batch["wcs_click_date_sk"],
-                        batch["wcs_user_sk"]))
-    user = np.asarray(batch["wcs_user_sk"])[order]
-    item = np.asarray(batch["wcs_item_sk"])[order]
-    ctype = np.asarray(batch["wcs_click_type"])[order]
+    user = np.asarray(batch["wcs_user_sk"])
+    tstamp = np.asarray(batch["tstamp"], dtype=np.int64)
+    item = np.asarray(batch["wcs_item_sk"])
+    ctype = np.asarray(batch["wcs_click_type"])
     cats = np.asarray(item_categories)
-    is_purchase = (ctype == 2) & (cats[item] == target_category)
-    is_view = ctype == 0
-    out: list[np.ndarray] = []
-    purchase_idx = np.flatnonzero(is_purchase)
-    for p in purchase_idx:
-        lo = max(0, p - window)
-        seg = slice(lo, p)
-        same_user = user[seg] == user[p]
-        out.append(item[seg][same_user & is_view[seg]])
-    viewed = np.concatenate(out) if out else np.asarray([], dtype=np.int64)
+    is_view = ctype == VIEW
+    view_pos = np.flatnonzero(is_view)
+    views_before = np.cumsum(is_view) - is_view
+    purchase = np.flatnonzero((ctype == PURCHASE)
+                              & (cats[item] == target_category))
+    out = []
+    for back in range(1, views + 1):
+        rank = views_before[purchase] - back
+        p = purchase[rank >= 0]
+        v = view_pos[rank[rank >= 0]]
+        keep = (user[v] == user[p]) & (tstamp[p] - tstamp[v] <= seconds)
+        out.append(item[v[keep]])
+    viewed = np.concatenate(out).astype(np.int64)
     return ColumnBatch({"viewed_item": viewed,
                         "n": np.ones(len(viewed), dtype=np.int64)})
 
@@ -319,7 +349,8 @@ def run_pipeline_ops(batch: ColumnBatch, ops: list[dict]) -> ColumnBatch:
             batch = op_hash_join(batch, spec["build"], spec["left_key"],
                                  spec["right_key"])
         elif kind == "udf":
-            batch = op_udf(batch, spec["name"], **spec.get("kwargs", {}))
+            batch = op_udf(batch, spec["name"], spec.get("order_by"),
+                           **spec.get("kwargs", {}))
         else:
             raise ValueError(f"unknown operator {kind!r}")
     return batch

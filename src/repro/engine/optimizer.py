@@ -24,7 +24,7 @@ Rule-based passes over ``engine.logical`` trees, in order:
    ``ShuffleOutput`` (the consumer's fragments align with the radix
    partition), by a ``Scan`` whose table declares
    ``partitioned_by=(key, fanout)``, and it propagates through filters,
-   projections (rename-aware) and joins (probe rows never move). Two
+   projections (rename-aware) and joins (probe rows never move). Three
    elision rules consume it:
 
    * *combine elision* — an aggregate whose producing pipeline is
@@ -38,6 +38,12 @@ Rule-based passes over ``engine.logical`` trees, in order:
      ignored), or, when it is itself an already-co-partitioned
      pass-through, its producer's partition slices are read directly as
      the build input with no rewrite.
+   * *UDF distribution* — a UDF that declares a required distribution
+     (``map_udf(partition_by=..., order_by=...)``, a reducer over whole
+     groups such as TPCx-BB Q3's user sessions) gets a hash exchange on
+     its key at the row-shuffle fan-out, and starts the pipeline that
+     reads it; an input already partitioned by the key keeps its
+     pipeline and the exchange is elided.
 
    Elided pipelines record the property they relied on in
    ``Pipeline.partitioning`` (checked by ``QueryPlan.validate()`` and
@@ -584,6 +590,10 @@ class _Lowering:
             op = {"op": "udf", "name": node.name, "kwargs": node.kwargs}
             if node.broadcast:
                 op["broadcast"] = node.broadcast
+            if node.partition_by is not None:
+                pipe = self._distribute(pipe, node)
+                op["partition_by"] = node.partition_by
+                op["order_by"] = list(node.order_by)
             pipe.ops.append(op)
             pipe.schema = list(node.output_columns) \
                 if node.output_columns else None
@@ -598,6 +608,41 @@ class _Lowering:
         if isinstance(node, Aggregate):
             return self._build_aggregate(node)
         raise TypeError(f"not a logical node: {node!r}")
+
+    def _distribute(self, pipe: _Pipe, node: Udf) -> _Pipe:
+        """Give a UDF the distribution it requires: every row of one
+        ``partition_by`` value in one fragment. An input already
+        partitioned by the key (a declared table layout, an upstream
+        shuffle) or held by one fragment keeps its pipeline; any other
+        input ends its pipeline with a hash exchange on the key, at the
+        row-shuffle fan-out, and the UDF starts the next one."""
+        key = node.partition_by
+        prop = pipe.part
+        if self.elide and prop is not None \
+                and (prop[0] == key or prop[1] == 1):
+            pipe.relied = True
+            self.trace.append(
+                f"shuffle_elision: udf {node.name} exchange on {key} "
+                f"ELIDED (input already partitioned {_fmt_part(prop)})")
+            return pipe
+        if prop is not None or pipe.part_note:
+            why = f"input partitioned {_fmt_part(prop)}" if prop \
+                else pipe.part_note
+            self.trace.append(f"shuffle_elision: udf {node.name} exchange "
+                              f"on {key} kept ({why})")
+        parts = self._fanout(pipe.est_bytes, f"udf {node.name} exchange "
+                             f"on {key}")
+        name = self._close(pipe, self._shuffle_out(
+            key, parts, pipe.est_bytes, pipe.writers_est,
+            f"row shuffle on {key}"))
+        self.trace.append(
+            f"udf_distribution: udf {node.name} requires hash({key}) "
+            f"sorted by {node.order_by}; '{name}' shuffles on {key} at "
+            f"{parts} partitions")
+        return _Pipe(input=ShuffleInput(name), base_name=f"udf_{node.name}",
+                     schema=pipe.schema, est_bytes=pipe.est_bytes,
+                     part=(key, parts), input_part=(key, parts),
+                     col_widths=pipe.col_widths, writers_est=parts)
 
     def _build_join(self, node: Join) -> _Pipe:
         left = self.build(node.left)

@@ -28,7 +28,9 @@ the worker into a leading ``hash_join`` op.
 Other ops: {"op": "filter", "expr": [...]} | {"op": "project", "columns":
 [name | [name, value-expr], ...]} | {"op": "hash_agg", "keys": [...],
 "aggs": [[out, fn, col], ...]} | {"op": "udf", "name": ..., "kwargs": ...,
-"broadcast": {...}} (see ``operators.py`` for expression grammar).
+"broadcast": {...}, "partition_by": key, "order_by": [...]} (the last two
+when the UDF requires its input distributed by ``key`` and sorted; see
+``operators.py`` for expression grammar).
 
 Plans are rarely hand-built anymore: ``engine.logical`` provides the
 typed expression/plan builder and ``engine.optimizer`` lowers it to this
@@ -134,6 +136,8 @@ def _op_input_columns(op: dict) -> Optional[set]:
                                   if fn != "count"}
     if kind == "hash_join":
         return {op["left_key"]}
+    if kind == "udf" and op.get("partition_by") is not None:
+        return {op["partition_by"], *op.get("order_by", ())}
     return None
 
 
@@ -201,6 +205,20 @@ def pipeline_schemas(plan: "QueryPlan") -> dict[str, Optional[list]]:
     return schemas
 
 
+def _distributed_by(pipe: "Pipeline", key: str, by_name: dict) -> bool:
+    """Whether every row of one ``key`` value reaches the same fragment
+    of ``pipe``: one fragment, a relied-on partitioning by the key, or a
+    shuffle input partitioned by it."""
+    if pipe.fragments == 1 or (pipe.partitioning or {}).get("key") == key:
+        return True
+    if isinstance(pipe.input, ShuffleInput):
+        prod = by_name.get(pipe.input.from_pipeline)
+        return prod is not None and isinstance(prod.output, ShuffleOutput) \
+            and (prod.output.partition_by == key
+                 or prod.output.partitions == 1)
+    return False
+
+
 def _check_partitioning(pipe: "Pipeline", by_name: dict) -> list[str]:
     """Structural checks for a declared (relied-on) input partitioning:
     the property must be exactly what the upstream shuffle established —
@@ -255,7 +273,9 @@ class QueryPlan:
         different fan-outs, declared ``partitioning`` properties that
         disagree with the upstream shuffle (elided stages), op inputs and
         shuffle partition keys no upstream op produces, and a terminal
-        pipeline that never collects. Raises ``PlanValidationError`` listing every problem —
+        pipeline that never collects, and a UDF whose required
+        distribution the pipeline's input does not have. Raises
+        ``PlanValidationError`` listing every problem —
         these misfires otherwise surface as opaque KeyErrors deep in
         ``worker.py``."""
         errors: list[str] = []
@@ -330,6 +350,16 @@ class QueryPlan:
                 errors.extend(
                     f"pipeline {p.name!r}: {m}"
                     for m in _check_partitioning(p, by_name))
+            for op in p.ops:
+                key = op.get("partition_by") if op.get("op") == "udf" \
+                    else None
+                if key is not None and not _distributed_by(p, key, by_name):
+                    # A reducer over whole groups run on an input split
+                    # across fragments would see each group in pieces.
+                    errors.append(
+                        f"pipeline {p.name!r}: udf {op.get('name')!r} "
+                        f"requires its input distributed by {key!r}, but "
+                        "the pipeline reads no input partitioned by it")
             if isinstance(p.output, ShuffleOutput) \
                     and p.output.tier not in EXCHANGE_TIERS:
                 errors.append(

@@ -179,50 +179,74 @@ def q12_reference(lineitem: ColumnBatch, orders: ColumnBatch,
 # TPCx-BB Q3 — MapReduce-style UDF job over clickstreams
 # ---------------------------------------------------------------------------
 
+# The session key, and the order of a user's clicks: by time, ties broken
+# by item and click type (an exchange does not keep arrival order).
+BB_Q3_ORDER = ["wcs_user_sk", "tstamp", "wcs_item_sk", "wcs_click_type"]
+
+
 def bb_q3_logical(item_table_key: str, target_category: int = 3,
-                  window: int = 5,
+                  views: int = 5, seconds: int = 864000,
                   shuffle_partitions: int | None = 8) -> LogicalQuery:
-    """``shuffle_partitions`` only pins row shuffles; this query has
-    none after the agg-split optimization (the map pipeline partially
-    aggregates, so the combine fan-out is optimizer-owned)."""
+    """TPCx-BB Q3 (``DISTRIBUTE BY wcs_user_sk SORT BY wcs_user_sk,
+    tstamp`` and a reducer over each user's clicks): for every purchase
+    of an item in ``target_category``, the items of the user's last
+    ``views`` views at most ``seconds`` before it, counted by viewed
+    item. The UDF requires whole user sessions, so the optimizer puts a
+    hash exchange on ``wcs_user_sk`` ahead of it, at
+    ``shuffle_partitions``."""
+    tstamp = col("wcs_click_date_sk") * 86400 + col("wcs_click_time_sk")
     return (
         scan("clickstreams", ["wcs_user_sk", "wcs_click_date_sk",
                               "wcs_click_time_sk", "wcs_item_sk",
                               "wcs_click_type"])
+        .select("wcs_user_sk", tstamp.alias("tstamp"), "wcs_item_sk",
+                "wcs_click_type")
         .map_udf("clicks_before_purchase",
                  kwargs={"target_category": target_category,
-                         "window": window},
+                         "views": views, "seconds": seconds},
                  broadcast={"item_categories": {"key": item_table_key,
                                                 "column": "i_category_id"}},
-                 output_columns=["viewed_item", "n"])
+                 output_columns=["viewed_item", "n"],
+                 partition_by="wcs_user_sk", order_by=BB_Q3_ORDER)
         .group_by("viewed_item")
         .agg(sum_("n").alias("views"))
         .collect("tpcxbb_q3", shuffle_partitions=shuffle_partitions))
 
 
 def bb_q3_plan(item_table_key: str, target_category: int = 3,
-               window: int = 5, shuffle_partitions: int = 8,
-               top_k: int = 10) -> QueryPlan:
+               views: int = 5, seconds: int = 864000,
+               shuffle_partitions: int = 8) -> QueryPlan:
     return optimizer.plan(bb_q3_logical(item_table_key, target_category,
-                                        window, shuffle_partitions))
+                                        views, seconds, shuffle_partitions))
 
 
 def bb_q3_reference(clicks: ColumnBatch, item: ColumnBatch,
-                    target_category: int = 3, window: int = 5
-                    ) -> dict[int, int]:
-    order = np.lexsort((clicks["wcs_click_time_sk"],
-                        clicks["wcs_click_date_sk"], clicks["wcs_user_sk"]))
-    user = clicks["wcs_user_sk"][order]
-    item_sk = clicks["wcs_item_sk"][order]
-    ctype = clicks["wcs_click_type"][order]
+                    target_category: int = 3, views: int = 5,
+                    seconds: int = 864000) -> dict[int, int]:
+    """Views by viewed item over the whole table: every user's clicks in
+    ``BB_Q3_ORDER``, each qualifying purchase walked back over the user's
+    views."""
+    user = np.asarray(clicks["wcs_user_sk"])
+    tstamp = np.asarray(clicks["wcs_click_date_sk"], np.int64) * 86400 \
+        + np.asarray(clicks["wcs_click_time_sk"])
+    item_sk = np.asarray(clicks["wcs_item_sk"])
+    ctype = np.asarray(clicks["wcs_click_type"])
+    order = np.lexsort((ctype, item_sk, tstamp, user))
+    user, tstamp, item_sk, ctype = (a[order] for a in (user, tstamp,
+                                                       item_sk, ctype))
     cats = item["i_category_id"]
     counts: dict[int, int] = {}
     for p in np.flatnonzero((ctype == PURCHASE)
                             & (cats[item_sk] == target_category)):
-        lo = max(0, p - window)
-        for j in range(lo, p):
-            if user[j] == user[p] and ctype[j] == VIEW:
-                counts[int(item_sk[j])] = counts.get(int(item_sk[j]), 0) + 1
+        seen = 0
+        j = p - 1
+        while j >= 0 and user[j] == user[p] and seen < views:
+            if ctype[j] == VIEW:
+                seen += 1
+                if tstamp[p] - tstamp[j] <= seconds:
+                    counts[int(item_sk[j])] = \
+                        counts.get(int(item_sk[j]), 0) + 1
+            j -= 1
     return counts
 
 

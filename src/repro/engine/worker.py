@@ -41,6 +41,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core import memory as core_memory
+from repro.core import spans
 from repro.core.spans import span
 from repro.core.storage_service import ObjectStore
 from repro.engine import columnar, compile as engine_compile, operators, \
@@ -510,6 +511,7 @@ def _write_shuffle(parts, spec: FragmentSpec, metrics: FragmentMetrics,
         written += 1
         metrics.write_requests += 1
         metrics.write_bytes += len(data)
+        spans.count("exchange_bytes", len(data))
     if kill_after is not None:
         # The chaos-chosen prefix exceeded the non-empty partition count:
         # the worker still dies before its commit.

@@ -137,18 +137,31 @@ def q12_plan_handbuilt(shuffle_partitions: int = 8,
 
 
 def bb_q3_plan_handbuilt(item_table_key: str, target_category: int = 3,
-                         window: int = 5,
+                         views: int = 5, seconds: int = 864000,
                          shuffle_partitions: int = 8) -> QueryPlan:
-    map_pipe = Pipeline(
-        name="map_clicks",
+    scan_pipe = Pipeline(
+        name="scan_clicks",
         input=TableInput("clickstreams", ["wcs_user_sk", "wcs_click_date_sk",
                                           "wcs_click_time_sk", "wcs_item_sk",
                                           "wcs_click_type"]),
+        ops=[{"op": "project", "columns": [
+            "wcs_user_sk",
+            ["tstamp", ["add", ["mul", "wcs_click_date_sk",
+                                ["const", 86400]], "wcs_click_time_sk"]],
+            "wcs_item_sk", "wcs_click_type"]}],
+        output=ShuffleOutput(partition_by="wcs_user_sk",
+                             partitions=shuffle_partitions))
+    map_pipe = Pipeline(
+        name="map_clicks",
+        input=ShuffleInput("scan_clicks"),
         ops=[{"op": "udf", "name": "clicks_before_purchase",
               "kwargs": {"target_category": target_category,
-                         "window": window},
+                         "views": views, "seconds": seconds},
               "broadcast": {"item_categories": {"key": item_table_key,
-                                                "column": "i_category_id"}}}],
+                                                "column": "i_category_id"}},
+              "partition_by": "wcs_user_sk",
+              "order_by": ["wcs_user_sk", "tstamp", "wcs_item_sk",
+                           "wcs_click_type"]}],
         output=ShuffleOutput(partition_by="viewed_item",
                              partitions=shuffle_partitions))
     reduce_pipe = Pipeline(
@@ -157,4 +170,4 @@ def bb_q3_plan_handbuilt(item_table_key: str, target_category: int = 3,
         ops=[{"op": "hash_agg", "keys": ["viewed_item"],
               "aggs": [["views", "sum", "n"]]}],
         output=CollectOutput())
-    return QueryPlan("tpcxbb_q3", [map_pipe, reduce_pipe])
+    return QueryPlan("tpcxbb_q3", [scan_pipe, map_pipe, reduce_pipe])

@@ -75,11 +75,14 @@ def test_backend_parity_bb_q3(loaded_store):
         c = Coordinator(store, mode="elastic", backend=backend)
         c.register_table("clickstreams", keys["clickstreams"])
         plan = queries.bb_q3_plan(keys["item"][0])
-        plan.pipelines[0].fragments = len(keys["clickstreams"])
         res = c.execute(plan, query_id=f"par-bb-{backend}")
         out[backend] = dict(zip(res.result["viewed_item"].tolist(),
                                 res.result["views"].tolist()))
     assert out["numpy"] == out["jit"]
+    clicks = ColumnBatch.concat([columnar.deserialize(store.get(k))
+                                 for k in keys["clickstreams"]])
+    item = columnar.deserialize(store.get(keys["item"][0]))
+    assert out["jit"] == queries.bb_q3_reference(clicks, item)
 
 
 def test_run_pipeline_rejects_unknown_backend():

@@ -85,19 +85,18 @@ def test_q12(coordinator, loaded_store):
 
 
 def test_bb_q3_totals(coordinator, loaded_store):
+    """Whole user sessions: the optimizer's exchange on wcs_user_sk puts
+    every user's clicks, from all six objects, in one UDF call, so the
+    answer is the whole-table reference's."""
     store, keys = loaded_store
     plan = queries.bb_q3_plan(keys["item"][0])
-    # Pin one partition per map fragment so the per-partition reference
-    # matches the engine's (session windows are fragment-local).
-    plan.pipelines[0].fragments = len(keys["clickstreams"])
     res = coordinator.execute(plan, query_id=f"bbq3-{coordinator.mode}-t")
-    total_ref = 0
     item = columnar.deserialize(store.get(keys["item"][0]))
-    for k in keys["clickstreams"]:
-        part = columnar.deserialize(store.get(k))
-        counts = queries.bb_q3_reference(part, item)
-        total_ref += sum(counts.values())
-    assert int(res.result["views"].sum()) == total_ref
+    ref = queries.bb_q3_reference(_full(store, keys["clickstreams"]), item)
+    got = dict(zip(res.result["viewed_item"].tolist(),
+                   res.result["views"].tolist()))
+    assert got == ref
+    assert int(res.result["views"].sum()) == sum(ref.values())
 
 
 def test_plan_json_roundtrip():

@@ -244,13 +244,15 @@ def test_partition_count_hint_wins():
 def test_aggregate_combine_ignores_row_shuffle_hint():
     """The shuffle_partitions hint pins ROW shuffles only: after the
     agg-split pass the combine data is tiny, so a hinted wide combine
-    would just schedule mostly-empty final fragments. bb_q3's old
-    hand-plan 8-way reduce shuffle moved raw rows; the optimized plan
-    pre-aggregates in the map pipeline and combines at fan-out 1."""
+    would just schedule mostly-empty final fragments. bb_q3's row
+    shuffle is its user exchange, which takes the hint; the session
+    pipeline pre-aggregates and combines at fan-out 1."""
     plan = queries.bb_q3_plan("tables/item/part-00000",
                               shuffle_partitions=8)
-    assert plan.pipelines[0].output.partition_by == "viewed_item"
-    assert plan.pipelines[0].output.partitions == 1
+    assert plan.pipelines[0].output.partition_by == "wcs_user_sk"
+    assert plan.pipelines[0].output.partitions == 8
+    assert plan.pipelines[1].output.partition_by == "viewed_item"
+    assert plan.pipelines[1].output.partitions == 1
     # Same for keyed combines in hinted join queries (q12: 8-way join
     # shuffles, 1-way combine) and for global aggregates.
     q12 = queries.q12_plan(shuffle_partitions=8)
@@ -566,21 +568,15 @@ def test_parity_bb_q3(loaded_store, backend):
     store, keys = loaded_store
     c = _coordinator(store, keys, backend)
     item = columnar.deserialize(store.get(keys["item"][0]))
-    total_ref = 0
-    for k in keys["clickstreams"]:
-        part = columnar.deserialize(store.get(k))
-        total_ref += sum(queries.bb_q3_reference(part, item).values())
+    ref = queries.bb_q3_reference(_full(store, keys["clickstreams"]), item)
     out = {}
     for tag, plan in (("lowered", queries.bb_q3_plan(keys["item"][0])),
                       ("hand", golden_plans.bb_q3_plan_handbuilt(
                           keys["item"][0]))):
-        # Pin one partition per map fragment: session windows are
-        # fragment-local, matching the per-partition reference.
-        plan.pipelines[0].fragments = len(keys["clickstreams"])
         res = c.execute(plan, f"lp-bb-{tag}-{backend}")
         out[tag] = dict(zip(res.result["viewed_item"].tolist(),
                             res.result["views"].tolist()))
-        assert int(sum(out[tag].values())) == total_ref
+        assert out[tag] == ref
     assert out["lowered"] == out["hand"]
 
 

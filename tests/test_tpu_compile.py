@@ -18,8 +18,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import flash_attention as fa
 from repro.kernels import hash_join as hj
 from repro.kernels import segment_reduce as sr
+from repro.kernels import session_window as sw
 
 FRAGMENT_ROWS = 1_500_000          # TPC-H SF1 lineitem in 4 partitions
+SESSION_ROWS = 1 << 19             # TPCx-BB SF1 clicks in 8 partitions
 
 
 @pytest.fixture(scope="module")
@@ -70,6 +72,18 @@ def test_segment_reduce_compiles_to_mosaic(one_chip, groups):
     compiled = jax.jit(fn).lower(_spec((6, n), jnp.float32, one_chip),
                                  _spec((n,), jnp.int32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_session_window_compiles_at_fragment_size(one_chip):
+    n = SESSION_ROWS
+    cols = tuple(_spec((n,), dt, one_chip)
+                 for dt in (jnp.int32, jnp.int32, jnp.int32, jnp.int8))
+    scalar = _spec((), jnp.int32, one_chip)
+    compiled = sw.session_window.lower(
+        cols, scalar, _spec((31_250,), jnp.int32, one_chip), scalar,
+        scalar, names=sw.COLUMNS, views=5,
+        max_purchases=SESSION_ROWS >> 5).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_flash_attention_compiles_at_internlm2_widths(one_chip):
