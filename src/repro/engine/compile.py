@@ -38,11 +38,16 @@ specs into fused kernels:
   the partition assignment into one traced call and the aggregation then
   runs per partition slice — partial pre-agg shuffle plans (the
   optimizer's agg split) execute as one traced call per segment.
-* ``hash_agg`` lexsorts the group keys and hands the aggregate columns to
-  the Pallas segmented-reduction kernel (``kernels.segment_reduce``),
-  stacked so all same-mode aggregates reduce in a single kernel launch —
-  interpret mode on CPU, Mosaic on TPU, like the kernels in
-  ``kernels/ops.py``.
+* ``hash_agg`` gives each row a group id and hands the aggregate columns,
+  in input order, to the Pallas segmented-reduction kernel
+  (``kernels.segment_reduce``), stacked so all same-mode aggregates
+  reduce in a single kernel launch — interpret mode on CPU, Mosaic on
+  TPU, like the kernels in ``kernels/ops.py``. Integer keys whose value
+  ranges multiply to at most ``_MAX_KERNEL_GROUPS`` group by their
+  mixed-radix code, with no sort (``_dense_groups``); other keys are
+  lexsorted and each sorted row's group id is scattered back to its
+  input position. Past that many groups, sort and ``reduceat`` on the
+  host.
 * ``udf`` operators run the UDF's traced implementation where
   ``_TRACED_UDFS`` has one, through the device boundary: TPCx-BB Q3's
   session window is one jitted program (``kernels.session_window``) over
@@ -81,6 +86,7 @@ from __future__ import annotations
 import collections
 import functools
 import json
+import math
 import warnings
 
 import jax
@@ -990,56 +996,105 @@ def _pow2(n: int) -> int:
 _MAX_KERNEL_GROUPS = 1024
 
 
+def _dense_groups(batch: ColumnBatch, keys: list[str]):
+    """Group rows by integer keys whose value ranges multiply to at most
+    ``_MAX_KERNEL_GROUPS``, without a sort: the mixed-radix code of the
+    keys (first key most significant) is a group's rank in the
+    lexicographic order ``group_boundaries`` gives. Returns
+    ``(ids, counts, key_values)``, with each row's group id in input
+    order, or None where the keys are not such a domain."""
+    cols = [np.asarray(batch[k]) for k in keys]
+    if not all(c.dtype.kind in "iu" for c in cols):
+        return None
+    lows = [c.min() for c in cols]
+    radices = [int(c.max()) - int(lo) + 1 for c, lo in zip(cols, lows)]
+    size = math.prod(radices)
+    if size > _MAX_KERNEL_GROUPS:
+        return None
+    code = np.zeros(batch.num_rows, dtype=np.int32)
+    for c, lo, r in zip(cols, lows, radices):
+        # c - lo wraps in c's dtype; read unsigned it is exact, since
+        # every offset is below r <= 1024 and below 2**bits.
+        code *= r
+        code += (c - lo).view(f"u{c.itemsize}").astype(np.int32)
+    present = np.bincount(code, minlength=size)
+    groups = np.flatnonzero(present)
+    remap = np.empty(size, dtype=np.int32)
+    remap[groups] = np.arange(len(groups), dtype=np.int32)
+    out = {}
+    stride = size
+    for k, c, lo, r in zip(keys, cols, lows, radices):
+        stride //= r
+        # The same wrap back: lo + offset lands on the key's own value.
+        out[k] = ((groups // stride) % r).astype(c.dtype) + lo
+    return np.take(remap, code), present[groups], out
+
+
+def _reduce_on_device(batch: ColumnBatch, aggs: list[list],
+                      ids: np.ndarray, n_groups: int, out: dict) -> None:
+    """Reduce the sum, min and max aggregates of ``batch`` into
+    ``n_groups`` groups by each row's group id (``ids``, in any row
+    order) in the segmented-reduction kernel, into ``out``. Rows and
+    segments pad to powers of two so jit/pallas shapes recur across
+    fragments (padding rows carry segment id -1: reduced into nothing).
+    Same-mode aggregates stack into one kernel launch."""
+    n = len(ids)
+    n_pad = _pow2(n)
+    s_pad = _pow2(n_groups)
+    ids_pad = np.full(n_pad, -1, dtype=np.int32)
+    ids_pad[:n] = ids
+    for mode in ("sum", "min", "max"):
+        group = [(name, col) for name, fn, col in aggs if fn == mode]
+        if not group:
+            continue
+        # Each column casts to float32 as it is written into the slab;
+        # only the padding is zeroed.
+        vals = np.empty((len(group), n_pad), dtype=np.float32)
+        vals[:, n:] = 0
+        for row, (_, col) in enumerate(group):
+            vals[row, :n] = batch[col]
+        # Called through the module's global name, which a caller may
+        # wrap to count the kernel's work.
+        red = _on_device("segment_reduce", segment_reduce, vals, ids_pad,
+                         num_segments=s_pad, mode=mode,
+                         interpret=tpu.interpret())
+        for row, (name, _) in enumerate(group):
+            out[name] = red[row, :n_groups].astype(np.float64)
+
+
 def _run_hash_agg(batch: ColumnBatch, keys: list[str],
                   aggs: list[list]) -> ColumnBatch:
     if batch.num_rows == 0:
         return operators.op_hash_agg(batch, keys, aggs)
     n = batch.num_rows
-    order, starts, out = operators.group_boundaries(batch, keys)
-    seg_ids = np.zeros(n, dtype=np.int64)
-    if keys:
-        seg_ids[starts] = 1
-        seg_ids = np.cumsum(seg_ids) - 1
-        # order is a true permutation only in the keyed case; the global
-        # aggregate (keys=[]) reduces in input order.
+    dense = _dense_groups(batch, keys) if keys else None
+    if dense is not None:
+        spans.count("hash_agg.dense")
+        ids, counts, out = dense
     else:
-        order = None
-    n_groups = len(starts)
-    counts = np.diff(np.append(starts, n))
-    if n_groups > _MAX_KERNEL_GROUPS:
-        # The one-hot kernel is O(rows x groups): past this cardinality
-        # (e.g. bb_q3's per-item reduce) sort+reduceat wins by orders of
-        # magnitude, so keep the kernel for the low-cardinality TPC shape.
-        for name, fn, col in aggs:
-            if fn == "count":
-                continue
-            vals = np.asarray(batch[col], dtype=np.float64)
-            out[name] = operators._AGG_FNS[fn](
-                vals[order] if order is not None else vals, starts)
-    else:
-        # Pad rows and segments to powers of two so jit/pallas shapes
-        # recur across fragments (padding rows carry segment id -1:
-        # reduced into nothing). Same-mode aggregates stack into one
-        # kernel launch.
-        n_pad = _pow2(n)
-        s_pad = _pow2(n_groups)
-        ids = np.full(n_pad, -1, dtype=np.int32)
-        ids[:n] = seg_ids
-        for mode in ("sum", "min", "max"):
-            group = [(name, col) for name, fn, col in aggs if fn == mode]
-            if not group:
-                continue
-            vals = np.zeros((len(group), n_pad), dtype=np.float32)
-            for row, (_, col) in enumerate(group):
-                v = np.asarray(batch[col], dtype=np.float32)
-                vals[row, :n] = v[order] if order is not None else v
-            # Called through the module's global name, which a caller
-            # may wrap to count the kernel's work.
-            red = _on_device("segment_reduce", segment_reduce, vals, ids,
-                             num_segments=s_pad, mode=mode,
-                             interpret=tpu.interpret())
-            for row, (name, _) in enumerate(group):
-                out[name] = red[row, :n_groups].astype(np.float64)
+        if keys:
+            spans.count("hash_agg.sorted")
+        order, starts, out = operators.group_boundaries(batch, keys)
+        counts = np.diff(np.append(starts, n))
+        if len(starts) > _MAX_KERNEL_GROUPS:
+            # The one-hot kernel is O(rows x groups): past this
+            # cardinality (e.g. bb_q3's per-item reduce) sort+reduceat
+            # wins by orders of magnitude, so keep the kernel for the
+            # low-cardinality TPC shape.
+            ids = None
+            for name, fn, col in aggs:
+                if fn != "count":
+                    vals = np.asarray(batch[col], dtype=np.float64)
+                    out[name] = operators._AGG_FNS[fn](vals[order], starts)
+        else:
+            # The kernel reduces rows in any order: scatter each sorted
+            # row's group id back to its input position in place of
+            # gathering every value column through the sort.
+            ids = np.empty(n, dtype=np.int32)
+            ids[order] = np.repeat(np.arange(len(starts), dtype=np.int32),
+                                   counts)
+    if ids is not None:
+        _reduce_on_device(batch, aggs, ids, len(counts), out)
     for name, fn, _ in aggs:
         if fn == "count":
             out[name] = counts.astype(np.int64)

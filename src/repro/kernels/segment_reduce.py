@@ -1,8 +1,9 @@
-"""Pallas segmented reduction over sorted segment ids.
+"""Pallas segmented reduction by segment ids.
 
 Backs the query engine's ``hash_agg`` in the jit backend: values arrive
-sorted by group (the compiler lexsorts the keys), each row carrying its
-group id, and the kernel reduces every segment to one output slot.
+in any row order, each row carrying its group id, and the kernel reduces
+every segment to one output slot. Membership is a one-hot compare, so
+rows of one segment need not be adjacent.
 
 The grid is ``(segment blocks, row blocks)``. Per step a ``(C, block_n)``
 slab of value columns is expanded against a ``(block_n, seg_block)``

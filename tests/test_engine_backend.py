@@ -224,6 +224,78 @@ def test_hash_agg_high_cardinality_fallback():
         np.testing.assert_allclose(a[col], b[col], rtol=1e-9)
 
 
+def _agg_case(name, n=4096):
+    """``(columns, ops, counter)`` of one jit hash_agg case: the batch,
+    the pipeline ending in the aggregate, and the grouping counter the
+    jit backend must move."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    ints = rng.integers
+    v = rng.uniform(1, 100, n).round(2)
+    keys = ["k"]
+    ops = []
+    if name == "q1_int8":
+        cols = {"k": ints(0, 3, n).astype(np.int8),
+                "k2": ints(0, 2, n).astype(np.int8)}
+        keys = ["k", "k2"]
+    elif name == "negative":
+        cols = {"k": ints(-7, -2, n), "k2": ints(-3, 2, n).astype(np.int16)}
+        keys = ["k", "k2"]
+    elif name == "int64_offset":
+        cols = {"k": ints(0, 9, n) + np.int64(2**62),
+                "k2": np.int64(2**63 - 1) - ints(0, 3, n)}
+        keys = ["k", "k2"]
+    elif name == "domain_1024":
+        k = ints(-16, 16, n).astype(np.int8)
+        k2 = ints(0, 32, n).astype(np.int32)
+        k[:2], k2[:2] = (-16, 15), (0, 31)
+        cols = {"k": k, "k2": k2}
+        keys = ["k", "k2"]
+    elif name == "domain_1025":
+        cols = {"k": np.r_[0, 1024, ints(0, 1025, n - 2)]}
+    elif name == "filter_empties_group":
+        cols = {"k": ints(0, 5, n).astype(np.int8)}
+        ops = [{"op": "filter", "expr": ["ne", "k", 2]}]
+    elif name == "one_group":
+        cols = {"k": np.full(n, 3, dtype=np.int8)}
+    elif name == "float_key":
+        cols = {"k": ints(0, 4, n).astype(np.float64) / 2}
+    else:
+        raise ValueError(name)
+    cols["v"] = v
+    ops = ops + [{"op": "hash_agg", "keys": keys,
+                  "aggs": [["s", "sum", "v"], ["c", "count", "v"],
+                           ["lo", "min", "v"], ["hi", "max", "v"]]}]
+    dense = name not in ("domain_1025", "float_key")
+    return cols, ops, "hash_agg.dense" if dense else "hash_agg.sorted"
+
+
+@pytest.mark.parametrize("name", [
+    "q1_int8", "negative", "int64_offset", "domain_1024", "domain_1025",
+    "filter_empties_group", "one_group", "float_key"])
+def test_hash_agg_grouping_parity(name):
+    """The jit hash_agg, dense (mixed-radix code, no sort) or sorted,
+    gives op_hash_agg's keys, key dtypes and group order exactly, its
+    aggregates to rtol 1e-6, and counts which way it grouped."""
+    from repro.core import spans
+    cols, ops, counter = _agg_case(name)
+    batch = ColumnBatch(cols)
+    want = engine_compile.run_pipeline(batch, ops, backend="numpy")
+    before = dict(spans.COUNTERS)
+    got = engine_compile.run_pipeline(batch, ops, backend="jit")
+    moved = {k: n - before.get(k, 0) for k, n in spans.COUNTERS.items()
+             if k.startswith("hash_agg.") and n != before.get(k, 0)}
+    assert moved == {counter: 1}
+    assert list(got) == list(want)
+    for k in ops[-1]["keys"]:
+        assert got[k].dtype == want[k].dtype
+        np.testing.assert_array_equal(got[k], want[k])
+    np.testing.assert_array_equal(got["c"], want["c"])
+    for col in ("s", "lo", "hi"):
+        np.testing.assert_allclose(got[col], want[col], rtol=1e-6)
+    if name == "filter_empties_group":
+        assert 2 not in got["k"] and len(got["k"]) == 4
+
+
 def test_fused_segment_wide_int_fallback():
     """int64 values beyond int32 range must not be truncated by the jit
     boundary: the segment falls back to the interpreted path."""

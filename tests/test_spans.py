@@ -1,6 +1,7 @@
 """The span recorder: nesting, query ids, threads, errors, the ring's
 bound, the spans of a served query and their attributes in a profiler
 trace, and the measured stage seconds that ``explain`` prints."""
+import collections
 import glob
 import os
 import threading
@@ -170,3 +171,30 @@ def test_explain_prints_measured_stage_seconds(server):
         assert (f"  {name}: start={m['start']:.3f}s end={m['end']:.3f}s "
                 f"duration={m['duration']:.3f}s "
                 f"measured={m['measured_s']:.3f}s ") in text
+
+
+def test_served_aggregations_count_how_they_grouped(server, monkeypatch):
+    """Q1's and Q12's group keys are small integer domains, so each of
+    their keyed aggregations groups with no sort, and counts so in a
+    span below its query's ``engine.serve`` span."""
+    ring = spans.SpanRing(capacity=1 << 14)
+    monkeypatch.setattr(spans, "RING", ring)
+    server.serve([QueryRequest(queries.q1_logical(90)),
+                  QueryRequest(queries.q12_logical(8, 731))])
+    records, dropped = ring.snapshot()
+    assert not dropped
+    by_id = {r.id: r for r in records}
+
+    def root(r):
+        while r.parent is not None:
+            r = by_id[r.parent]
+        return r
+
+    counts = collections.Counter()
+    for r in records:
+        mine = {k: n for k, n in r.attrs.items() if k.startswith("hash_agg.")}
+        if mine:
+            assert root(r).name == "engine.serve"
+            counts.update(mine)
+    assert set(counts) == {"hash_agg.dense"}
+    assert counts["hash_agg.dense"] >= 2      # at least one a query
